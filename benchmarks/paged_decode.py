@@ -151,8 +151,8 @@ def bench_churn_throughput(report: BenchReport, smoke: bool) -> list[str]:
         page = jnp.where(live, table[jnp.arange(b), lens // ps], 0)
         off = lens % ps
         knew = jax.random.normal(jax.random.PRNGKey(0), (b, hkv, d), jnp.float32)
-        kp = kp.at[page, off].set(knew)
-        vp = vp.at[page, off].set(knew)
+        kp = kp.at[:, page, off].set(knew.swapaxes(0, 1))
+        vp = vp.at[:, page, off].set(knew.swapaxes(0, 1))
         new_len = jnp.where(live, lens + 1, 0)
         return paged_decode_attention(q, kp, vp, table, new_len), kp, vp
 

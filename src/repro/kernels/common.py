@@ -1,23 +1,18 @@
 """Shared kernel plumbing.
 
-All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-VALIDATED in interpret mode on CPU (the container has no TPU).  The
-`interpret_default()` switch keeps `ops.py` wrappers runnable everywhere:
-real lowering on TPU, interpreter elsewhere.  `REPRO_PALLAS_INTERPRET=0/1`
-overrides.
+All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling).  The
+tests run on the CPU (``JAX_PLATFORMS=cpu``), where `interpret_default()`
+puts every kernel into Pallas interpret mode; on any other backend the
+kernels are lowered by Mosaic.  The chip is exercised by
+``chip_smoke.py`` at the repository root.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def cdiv(a: int, b: int) -> int:

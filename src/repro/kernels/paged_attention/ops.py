@@ -35,7 +35,7 @@ def paged_decode_attention(
     q, k_pages, v_pages, page_table, kv_len, bk: int | None = None,
     use_pallas: bool = True,
 ):
-    """q: (B,Hq,D); pages (P,ps,Hkv,D); page_table (B,max_pages); kv_len (B,).
+    """q: (B,Hq,D); pages (Hkv,P,ps,D); page_table (B,max_pages); kv_len (B,).
 
     The table must cover every row's ``kv_len`` (unused entries point at
     the null page); ``kv_len == 0`` rows return exact zeros.
@@ -49,8 +49,8 @@ def paged_decode_attention(
 
 
 def init_page_arrays(n_pages, page_size, n_kv_heads, head_dim, dtype=jnp.bfloat16):
-    """Zeroed device K and V page pools, ``(n_pages, ps, Hkv, Dh)`` each."""
-    z = jnp.zeros((n_pages, page_size, n_kv_heads, head_dim), dtype)
+    """Zeroed device K and V page pools, heads-major ``(Hkv, n_pages, ps, Dh)``."""
+    z = jnp.zeros((n_kv_heads, n_pages, page_size, head_dim), dtype)
     return z, z
 
 
@@ -58,13 +58,13 @@ def init_page_arrays(n_pages, page_size, n_kv_heads, head_dim, dtype=jnp.bfloat1
 def pack_prefill_pages(k_pages, v_pages, k_dense, v_dense, page_ids):
     """Scatter one request's prefilled K/V into its pool pages.
 
-    ``k_pages``/``v_pages``: (..., P, ps, Hkv, Dh) pools (a leading layer
+    ``k_pages``/``v_pages``: (..., Hkv, P, ps, Dh) pools (a leading layer
     axis is fine); ``k_dense``/``v_dense``: (..., S, Hkv, Dh) the request's
     prefill rows; ``page_ids``: (n,) int32 with ``n * ps >= S`` (the tail
     of the last page is zero-filled — positions ``>= kv_len`` are masked
     by the kernel anyway).
     """
-    ps = k_pages.shape[-3]
+    ps = k_pages.shape[-2]
     s = k_dense.shape[-3]
     n = page_ids.shape[0]
     pad = [(0, 0)] * k_dense.ndim
@@ -72,17 +72,17 @@ def pack_prefill_pages(k_pages, v_pages, k_dense, v_dense, page_ids):
 
     def pack(pages, dense):
         lead = dense.shape[:-3]
-        paged = jnp.pad(dense, pad).reshape(
-            lead + (n, ps) + dense.shape[-2:]
-        ).astype(pages.dtype)
-        return pages.at[..., page_ids, :, :, :].set(paged)
+        paged = jnp.pad(dense, pad).reshape(lead + (n, ps) + dense.shape[-2:])
+        # (..., n, ps, Hkv, Dh) -> heads-major (..., Hkv, n, ps, Dh)
+        paged = jnp.moveaxis(paged, -2, -4).astype(pages.dtype)
+        return pages.at[..., page_ids, :, :].set(paged)
 
     return pack(k_pages, k_dense), pack(v_pages, v_dense)
 
 
 def apply_page_permutation(pages, perm):
     """Reorder device pages after `PagedKVPool.defrag` (``perm[new] = old``)."""
-    return pages[..., jnp.asarray(perm), :, :, :]
+    return pages[..., jnp.asarray(perm), :, :]
 
 
 # --------------------------------------------------------------------------

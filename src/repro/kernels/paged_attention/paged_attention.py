@@ -3,8 +3,10 @@
 Same flash-decode shape as `repro.kernels.decode_attention` — grid
 (B, Hkv, blocks) streaming the cache in (bk, D) VMEM tiles, all `group`
 q-heads sharing a KV head processed as one (group, D) tile — except the
-cache is a **page pool** ``(n_pages, page_size, Hkv, D)`` addressed
-through per-row page tables instead of a dense ``(B, S, Hkv, D)`` slab.
+cache is a heads-major **page pool** ``(Hkv, n_pages, page_size, D)``
+addressed through per-row page tables instead of a dense
+``(B, S, Hkv, D)`` slab.  Heads-major keeps every block's last two dims
+``(bk, D)`` and ``(group, D)``, the tile shapes Mosaic accepts.
 
 The page table and per-row ragged lengths ride in as **scalar-prefetch**
 arguments (`pltpu.PrefetchScalarGridSpec`), so the KV BlockSpec index map
@@ -48,27 +50,29 @@ def _kernel(
 
     @pl.when(bi * bk < kv_len)
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (group, bk)
+        q = q_ref[0, 0].astype(jnp.float32)  # (group, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (group, bk)
         pos = bi * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (group, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc[...] = acc[...] * corr[:, None] + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(bi == n_blk - 1)
     def _flush():
         # kv_len == 0 rows never ran `_step`; flush exact zeros, not 0/0
         l = l_ref[...]
-        out = acc[...] / jnp.where(l > 0.0, l, 1.0)[:, None]
-        out = jnp.where((l > 0.0)[:, None], out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        out = acc[...] / jnp.where(l > 0.0, l, 1.0)
+        out = jnp.where(l > 0.0, out, 0.0)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -76,11 +80,11 @@ def paged_decode_attention_pallas(
     q, k_pages, v_pages, page_table, kv_len, bk: int | None = None,
     interpret: bool = True,
 ):
-    """q: (B,Hq,D); pages (P,ps,Hkv,D); page_table (B,max_pages) int32;
+    """q: (B,Hq,D); pages (Hkv,P,ps,D); page_table (B,max_pages) int32;
     kv_len (B,) int32 -> (B,Hq,D).
     """
     b, hq, d = q.shape
-    _, ps, hkv, _ = k_pages.shape
+    hkv, _, ps, _ = k_pages.shape
     assert hq % hkv == 0
     group = hq // hkv
     bk = ps if bk is None else max(1, min(int(bk), ps))
@@ -90,30 +94,29 @@ def paged_decode_attention_pallas(
     n_blk = max_pages * sub
     grid = (b, hkv, n_blk)
 
-    # view q as (B, group, Hkv, D) so one KV-head block feeds `group` heads
-    q4 = q.reshape(b, hkv, group, d).transpose(0, 2, 1, 3)
-    q_spec = pl.BlockSpec((1, group, 1, d), lambda bb, h, bi, tab, ln: (bb, 0, h, 0))
+    # q head h*group + g attends KV head h: view q as (B, Hkv, group, D)
+    q4 = q.reshape(b, hkv, group, d)
+    q_spec = pl.BlockSpec((1, 1, group, d), lambda bb, h, bi, tab, ln: (bb, h, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, bk, 1, d),
-        lambda bb, h, bi, tab, ln: (tab[bb, bi // sub], bi % sub, h, 0),
+        (1, 1, bk, d),
+        lambda bb, h, bi, tab, ln: (h, tab[bb, bi // sub], bi % sub, 0),
     )
-    o_spec = pl.BlockSpec((1, group, 1, d), lambda bb, h, bi, tab, ln: (bb, 0, h, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         partial(_kernel, scale=1.0 / (d**0.5), bk=bk, n_blk=n_blk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, hkv, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), q4, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3).reshape(b, hq, d)
+    return out.reshape(b, hq, d)

@@ -1,7 +1,7 @@
 """Host-side paged KV-cache pool: fixed-size pages, per-request page tables.
 
 The pool owns page *ids* only — the actual K/V page arrays live on device
-(``(L, n_pages, page_size, Hkv, Dh)``, see `ops.init_page_arrays` and the
+(heads-major ``(L, Hkv, n_pages, page_size, Dh)``, see `ops.init_page_arrays` and the
 model's ``init_paged_cache``).  Page 0 is reserved as the **null page**:
 free table slots point at it, and padded batch rows (``kv_len == 0``)
 write their dead token there, so a table is always fully populated with
@@ -11,7 +11,7 @@ Allocation is all-or-nothing (a request either gets every page it asked
 for or ``None`` — no partial grants to unwind), frees return pages to a
 LIFO free stack (hot reuse), and :meth:`defrag` compacts the in-use pages
 to the low end of the pool, returning the gather permutation to apply to
-the device arrays (``pages[perm]``).
+the device arrays (`ops.apply_page_permutation`).
 """
 from __future__ import annotations
 

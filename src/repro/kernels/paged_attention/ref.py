@@ -24,11 +24,11 @@ def ragged_decode_ref(q, k_cache, v_cache, kv_len):
 
 
 def gather_pages(pages, page_table):
-    """(P,ps,Hkv,D) pages + (B,max_pages) table -> dense (B,max_pages*ps,Hkv,D)."""
+    """(Hkv,P,ps,D) pages + (B,max_pages) table -> dense (B,max_pages*ps,Hkv,D)."""
     b, n = page_table.shape
-    _, ps, hkv, d = pages.shape
-    dense = pages[page_table.reshape(-1)]  # (B*n, ps, Hkv, D)
-    return dense.reshape(b, n * ps, hkv, d)
+    hkv, _, ps, d = pages.shape
+    dense = pages[:, page_table.reshape(-1)]  # (Hkv, B*n, ps, D)
+    return dense.reshape(hkv, b, n * ps, d).transpose(1, 2, 0, 3)
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, page_table, kv_len):

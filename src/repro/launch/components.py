@@ -62,9 +62,7 @@ def _unrolled(run: RunConfig) -> RunConfig:
 
 
 def _cost_of(lowered) -> StepCost:
-    from .roofline import cost_analysis_dict
-
-    ca = cost_analysis_dict(lowered.compile())
+    ca = lowered.compile().cost_analysis()
     return StepCost(
         flops=float(ca.get("flops", 0.0)),
         hbm_bytes=float(ca.get("bytes accessed", 0.0)),

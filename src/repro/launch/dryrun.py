@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks the device count on first
-# init).  Only the dry-run forces 512 host devices; tests/benches see 1.
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 Per cell this produces (cached as JSON under experiments/dryrun/):
@@ -21,6 +16,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -54,7 +50,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, run_cfg=None,
     t_compile = time.time() - t0 - t_lower
     mem = rl.memory_analysis_dict(compiled)
     print(compiled.memory_analysis())
-    ca = rl.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print({k: v for k, v in ca.items() if k in ("flops", "bytes accessed")})
     colls = rl.collective_wire_bytes(compiled.as_text())
 
@@ -178,4 +174,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # before jax first initialises a backend (it locks the device count):
+    # only the dry-run process forces 512 host devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

@@ -96,7 +96,7 @@ def collective_wire_bytes(hlo_text: str) -> dict:
 
 def cost_of_lowered(lowered) -> StepCost:
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     coll = collective_wire_bytes(compiled.as_text())["total"]
@@ -193,17 +193,6 @@ def model_flops(cfg, shape) -> float:
     if shape.kind == "prefill":
         return 2.0 * n * tokens
     return 2.0 * n * shape.global_batch  # one token per sequence
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` normalised across jax versions: older
-    releases return a one-element list of dicts, newer ones a plain dict."""
-    ca = compiled.cost_analysis()
-    if not ca:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
 
 
 def memory_analysis_dict(compiled) -> dict:

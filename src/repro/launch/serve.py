@@ -79,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.attrib import EnergyLedger, KernelSpan, attribute_block, render_text
+from repro.compile_cache import place_compile_cache, place_tpu_logs
 from repro.configs import RunConfig, get_config, smoke_config
 from repro.models import build_model
 from repro.obs import trace as obs_trace
@@ -96,6 +97,10 @@ from repro.sched import (
 #: k .. k+1 of it (wave-era goldens use the same char, one wave = one
 #: interval)
 _STEP_MARK = "W"
+
+#: serving numerics: jnp prefill attention, no remat, bf16 weights
+SERVE_RUN = RunConfig(attn_impl="full", remat="none", lr_chunk=16,
+                      param_dtype="bfloat16")
 
 
 def _make_fleet(n_devices: int, total_watts: float, seed: int):
@@ -212,9 +217,9 @@ def main(argv=None):
     if args.kv == "paged" and (cfg.is_encdec or cfg.family not in ("dense", "moe")):
         ap.error(f"--kv paged needs dense/moe attention layers; "
                  f"{args.arch} is family {cfg.family!r}")
-    run = RunConfig(attn_impl="full", remat="none", lr_chunk=16)
-    model = build_model(cfg, run)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    model = build_model(cfg, SERVE_RUN)
+    # one program draws and casts every leaf: no float32 tree on the device
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
 
     b = args.decode_batch
@@ -546,9 +551,11 @@ def main(argv=None):
         pending.clear()
     dt = time.perf_counter() - t0
     s = telemetry.summary()
+    dev = jax.devices()[0]
     print(f"served {len(sched.finished)}/{args.requests} requests "
           f"({len(sched.rejected)} rejected by SLO), {billed_tokens} tokens in "
-          f"{dt:.2f}s ({billed_tokens/dt:.1f} tok/s wall on CPU) "
+          f"{dt:.2f}s ({billed_tokens/dt:.1f} tok/s wall on {dev.platform} "
+          f"{dev.device_kind}, compiles included) "
           f"over {step_count} decode steps / {n_intervals} {args.policy} intervals")
     if decoded_tokens:
         print(f"slot utilization: {billed_tokens}/{decoded_tokens} decoded "
@@ -609,7 +616,15 @@ def main(argv=None):
         with open(args.metrics, "w") as fh:
             fh.write(obs_export.prometheus_text(obs_metrics.active()))
         print(f"wrote metrics snapshot to {args.metrics}")
+    return {
+        "served": len(sched.finished),
+        "rejected": len(sched.rejected),
+        "billed_tokens": billed_tokens,
+        "decode_steps": step_count,
+    }
 
 
 if __name__ == "__main__":
+    place_tpu_logs()
+    place_compile_cache()
     main()

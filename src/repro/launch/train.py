@@ -17,6 +17,7 @@ from dataclasses import replace
 
 import jax
 
+from repro.compile_cache import place_compile_cache, place_tpu_logs
 from repro.configs import ALIASES, RunConfig, get_config, smoke_config
 from repro.data import SyntheticTokens
 from repro.launch import mesh as mesh_lib
@@ -59,7 +60,9 @@ def make_recording_attributor(path, telemetry, seed: int = 0, **kwargs):
     return _RecordingAttributor()
 
 
-def main(argv=None):
+def main(argv=None, cfg=None):
+    """Run the training CLI; ``cfg`` replaces the ``--arch``/``--smoke``
+    choice with a given `ArchConfig` (e.g. a published config cut in depth)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -82,7 +85,8 @@ def main(argv=None):
                          "record the session to a replayable trace archive")
     args = ap.parse_args(argv)
 
-    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if cfg is None:
+        cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     run = RunConfig(attn_impl="full" if args.seq <= 512 else "chunked",
                     remat="none" if args.smoke else "layer", lr_chunk=16)
     model = build_model(cfg, run)
@@ -147,4 +151,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    place_tpu_logs()
+    place_compile_cache()
     main()

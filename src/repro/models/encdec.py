@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.configs import ArchConfig, RunConfig
 
 from .layers import attention, full_attention, layernorm, mlp_gelu
-from .params import dense_init, embed_init, stack_layers
+from .params import cast_tree, dense_init, embed_init, stack_layers
 from .transformer import _dt, _qkv, init_attn
 
 
@@ -84,9 +84,10 @@ class EncDecLM:
     run: RunConfig = RunConfig()
 
     def init(self, key) -> dict:
+        """Random parameters in ``run.param_dtype`` (see `DecoderLM.init`)."""
         cfg = self.cfg
         ks = jax.random.split(key, 5)
-        return {
+        params = {
             "enc_in": dense_init(ks[0], cfg.d_model, cfg.d_model),  # frame adapter (stub stem)
             "embed": embed_init(ks[1], cfg.vocab_padded, cfg.d_model),
             "dec_pos": 0.01 * jax.random.normal(ks[2], (32768, cfg.d_model), jnp.float32),
@@ -95,6 +96,7 @@ class EncDecLM:
             "enc_norm": _ln_init(cfg.d_model),
             "dec_norm": _ln_init(cfg.d_model),
         }
+        return cast_tree(params, self.run.param_dtype)
 
     # ------------------------------------------------------------- encoder
     def encode(self, params, frames):

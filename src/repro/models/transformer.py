@@ -29,7 +29,7 @@ from . import rwkv as rwkv_mod
 from . import ssm as ssm_mod
 from .layers import apply_rope, attention, full_attention, mlp_swiglu, rmsnorm
 from .moe import moe_layer
-from .params import dense_init, embed_init, stack_layers
+from .params import cast_tree, dense_init, embed_init, stack_layers
 
 
 def _dt(run: RunConfig):
@@ -101,7 +101,7 @@ def attn_block_decode_paged(
 ):
     """Single-token attention against a paged KV pool.
 
-    x: (B, d); pages: (P, ps, Hkv, Dh); page_table: (B, max_pages) int32;
+    x: (B, d); pages: (Hkv, P, ps, Dh); page_table: (B, max_pages) int32;
     kv_len: (B,) tokens already cached per row; live: (B,) bool.  Each live
     row writes its new K/V at position ``kv_len[b]`` inside the page the
     table maps it to; dead rows (free slots) write to the reserved null
@@ -111,13 +111,14 @@ def attn_block_decode_paged(
     from repro.kernels.paged_attention import NULL_PAGE, paged_decode_attention
 
     b, _ = x.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     q, k, v = _qkv(x[:, None], p, cfg, kv_len[:, None], rope=True)
     cdt = k_pages.dtype
     page = jnp.where(live, page_table[jnp.arange(b), kv_len // ps], NULL_PAGE)
     off = kv_len % ps
-    k_pages = k_pages.at[page, off].set(k[:, 0].astype(cdt))
-    v_pages = v_pages.at[page, off].set(v[:, 0].astype(cdt))
+    # (B, Hkv, Dh) rows -> the (Hkv, B, Dh) slice `[:, page, off]` selects
+    k_pages = k_pages.at[:, page, off].set(k[:, 0].swapaxes(0, 1).astype(cdt))
+    v_pages = v_pages.at[:, page, off].set(v[:, 0].swapaxes(0, 1).astype(cdt))
     new_len = jnp.where(live, kv_len + 1, 0)
     o = paged_decode_attention(q[:, 0], k_pages, v_pages, page_table, new_len)
     o = o.reshape(b, -1).astype(x.dtype)
@@ -282,6 +283,11 @@ class DecoderLM:
 
     # ----------------------------------------------------------- init
     def init(self, key) -> dict:
+        """Random parameters in ``run.param_dtype``.
+
+        Leaves are drawn in float32 and cast; call under `jax.jit` so a
+        narrower dtype never holds the float32 tree on the device.
+        """
         cfg = self.cfg
         ks = jax.random.split(key, 6)
         params = {
@@ -305,7 +311,7 @@ class DecoderLM:
             }
         else:
             params["layers"] = stack_layers(lambda k: init_layer(k, cfg), ks[2], cfg.n_layers)
-        return params
+        return cast_tree(params, self.run.param_dtype)
 
     def _hybrid_layout(self):
         g = self.cfg.n_layers // self.cfg.attn_every
@@ -488,7 +494,7 @@ class DecoderLM:
     def init_paged_cache(self, n_pages: int, page_size: int):
         """Allocate the paged decode cache: per-layer K/V page pools.
 
-        Returns ``{"layers": {"k": (L, P, ps, Hkv, Dh), "v": ...}}`` — no
+        Returns ``{"layers": {"k": (L, Hkv, P, ps, Dh), "v": ...}}`` — no
         ``pos`` clock: position is per-row ragged ``kv_len``, owned by the
         host-side `repro.kernels.paged_attention.PagedKVPool`.  Dense/moe
         families only (ssm/hybrid keep recurrent state, nothing to page).
@@ -497,7 +503,7 @@ class DecoderLM:
         if cfg.family not in ("dense", "moe"):
             raise ValueError(f"paged KV cache needs attention layers, not {cfg.family!r}")
         cdt = jnp.dtype(run.decode_cache_dtype)
-        pool = jnp.zeros((cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim_), cdt)
+        pool = jnp.zeros((cfg.n_layers, cfg.n_kv_heads, n_pages, page_size, cfg.head_dim_), cdt)
         return {"layers": {"k": pool, "v": pool.copy()}}
 
     def prefill(self, params, tokens, max_len: int | None = None):

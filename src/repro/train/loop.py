@@ -89,12 +89,14 @@ def train(
             data.load_state_dict(extra["data_state"])
             start_step = extra["step"]
     if params is None:
-        params = model.init(jax.random.PRNGKey(loop_cfg.seed))
-        if shardings is not None:
-            params = jax.tree.map(jax.device_put, params, shardings["params"])
-        opt_state = init_opt_state(params)
-        if shardings is not None:
-            opt_state = jax.tree.map(jax.device_put, opt_state, shardings["opt"])
+        # built under jit straight into their shardings: no device ever
+        # holds the whole unsharded tree
+        params = jax.jit(
+            model.init, out_shardings=shardings["params"] if shardings else None
+        )(jax.random.PRNGKey(loop_cfg.seed))
+        opt_state = jax.jit(
+            init_opt_state, out_shardings=shardings["opt"] if shardings else None
+        )(params)
         data.step = 0
 
     saver = (

@@ -1,0 +1,112 @@
+"""AOT compiles for a described TPU v5e: the serving path at qwen2.5-3b width.
+
+Nothing runs: the TPU compiler installed with JAX compiles for a chip
+that is described, not attached, and refuses what the chip would refuse
+(block shapes Mosaic cannot tile, programs that do not fit its HBM).
+Interpret-mode tests cannot see either.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and every
+test worker imports this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.compile_cache import TPU_LOG_DEFAULT
+from repro.configs import get_config
+from repro.kernels.paged_attention import paged_decode_attention_pallas
+from repro.launch.serve import SERVE_RUN
+from repro.models import build_model
+
+#: usable HBM of one v5e chip as its compiler reports it (15.75 GiB)
+V5E_HBM_BYTES = int(15.75 * 2**30)
+B, PROMPT, PAGE, TABLE = 4, 128, 16, 10  # the serve smoke's shapes
+N_PAGES = 1 + B * TABLE
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        # libtpu loads here and logs under /tmp unless told otherwise
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", TPU_LOG_DEFAULT))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without one: keep the cache out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def qwen(one_chip):
+    """The full-width model with bf16 weight shapes placed on one chip."""
+    model = build_model(get_config("qwen2.5-3b"), SERVE_RUN)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return model, _on(params, one_chip)
+
+
+def _on(tree, sharding):
+    return jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding), tree
+    )
+
+
+def _hbm_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("bk", [None, 8])
+def test_paged_kernel_compiles_at_qwen_widths(one_chip, dtype, bk):
+    cfg = get_config("qwen2.5-3b")
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda q, k, v, t, n: paged_decode_attention_pallas(
+            q, k, v, t, n, bk=bk, interpret=False)
+    ).lower(
+        sds((B, hq, d), dtype), sds((hkv, N_PAGES, PAGE, d), dtype),
+        sds((hkv, N_PAGES, PAGE, d), dtype), sds((B, TABLE), jnp.int32),
+        sds((B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_full_width_prefill_fits_one_chip(one_chip, qwen):
+    model, params = qwen
+    assert all(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(params))
+    tokens = jax.ShapeDtypeStruct((B, PROMPT), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, t: model.prefill(p, t, max_len=PROMPT)).lower(
+        params, tokens).compile()
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
+def test_full_width_paged_decode_step_fits_and_holds_kernel(one_chip, qwen, monkeypatch):
+    import repro.kernels.paged_attention.ops as paged_ops
+
+    # the described chip is not the default backend, which would pick the
+    # interpreter: lower the kernel as the chip itself does
+    monkeypatch.setattr(paged_ops, "interpret_default", lambda: False)
+    model, params = qwen
+    cache = _on(jax.eval_shape(lambda: model.init_paged_cache(N_PAGES, PAGE)), one_chip)
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    live = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(model.decode_step_paged).lower(
+        params, cache, i32((B,)), i32((B, TABLE)), i32((B,)), live).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES

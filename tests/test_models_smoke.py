@@ -146,3 +146,15 @@ def test_moe_sort_matches_einsum_when_no_drops():
     l1, _ = jax.jit(m_e.loss_fn)(params, batch)
     l2, _ = jax.jit(m_s.loss_fn)(params, batch)
     np.testing.assert_allclose(float(l1), float(l2), rtol=2e-3)
+
+
+@pytest.mark.parametrize("arch", ["qwen25_3b", "whisper_base"])
+def test_init_honours_param_dtype(arch):
+    """Serving draws bf16 weights; training keeps its float32 default."""
+    from dataclasses import replace
+
+    cfg = smoke_config(arch)
+    for dtype in ("bfloat16", "float32"):
+        model = build_model(cfg, replace(RUN, param_dtype=dtype))
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        assert {l.dtype for l in jax.tree.leaves(params)} == {jnp.dtype(dtype)}

@@ -63,11 +63,13 @@ def test_serve_paged_kv_backend_end_to_end(capsys):
     # paged cache backend on an attention arch: admission allocates pages,
     # retire frees them — every page is back in the pool at exit, and churn
     # over more requests than slots actually reuses freed pages
-    serve.main([
+    res = serve.main([
         "--arch", "qwen25-3b", "--smoke", "--kv", "paged", "--page-size", "8",
         "--requests", "5", "--gen-len", "4", "--prompt-len", "8",
         "--decode-batch", "2", "--fleet", "2", "--arrive-every", "2",
     ])
+    assert res["served"] == 5 and res["rejected"] == 0
+    assert res["billed_tokens"] == 20 and res["decode_steps"] >= 4
     out = capsys.readouterr().out
     assert "served 5/5 requests" in out
     assert "(0 rejected by SLO), 20 tokens" in out  # 5 x 4, billed exactly once

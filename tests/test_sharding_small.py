@@ -99,8 +99,8 @@ _SUBPROC = textwrap.dedent(
     cell = build_cell(arch, shape_name, mesh, run)
     lowered = jax.jit(cell.fn, out_shardings=cell.out_shardings).lower(*cell.args)
     compiled = lowered.compile()
-    from repro.launch.roofline import collective_wire_bytes, cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    from repro.launch.roofline import collective_wire_bytes
+    ca = compiled.cost_analysis()
     colls = collective_wire_bytes(compiled.as_text())
     print(json.dumps({
         "flops": float(ca.get("flops", 0.0)),

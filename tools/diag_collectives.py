@@ -1,9 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Diagnostic: per-collective breakdown of a cell's sharded L=1 lowering.
 
     PYTHONPATH=src python tools/diag_collectives.py chameleon-34b train_4k [overrides...]
 """
+import os
 import re
 import sys
 from dataclasses import replace
@@ -27,7 +26,6 @@ def main():
         run = replace(run, **{k: (v if not v.isdigit() else int(v))
                               if v not in ("True", "False") else v == "True"})
     c1, c2, mult = _reduced_cfgs(cfg)
-    import os
     if os.environ.get('DIAG_L2'):
         c1 = c2
     mesh = mesh_lib.make_production_mesh()
@@ -52,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()
