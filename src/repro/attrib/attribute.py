@@ -28,6 +28,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
 from repro.stream.aggregate import cumulative_energy
 from repro.stream.ring import FrameBlock
 
@@ -343,10 +344,11 @@ def attribute_block(
     gap_factor: float = 3.0,
 ) -> EnergyLedger:
     """`attribute` over a `FrameRing` view (pair=None sums across pairs)."""
-    w = block.total_watts if pair is None else block.watts[:, pair]
-    return attribute(
-        block.times_s, w, spans, min_coverage=min_coverage, gap_factor=gap_factor
-    )
+    with obs_trace.span("attrib:block", spans=len(spans)):
+        w = block.total_watts if pair is None else block.watts[:, pair]
+        return attribute(
+            block.times_s, w, spans, min_coverage=min_coverage, gap_factor=gap_factor
+        )
 
 
 def refine_spans(

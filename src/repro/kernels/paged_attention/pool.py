@@ -19,6 +19,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 NULL_PAGE = 0
 
 
@@ -111,7 +113,9 @@ class PagedKVPool:
         """
         if rid in self._tables:
             raise KeyError(f"rid {rid} already allocated")
-        pages = self._grant(pages_for(n_tokens, self.page_size) + int(extra_pages))
+        n = pages_for(n_tokens, self.page_size) + int(extra_pages)
+        with obs_trace.span("pool:alloc", rid=rid, pages=n):
+            pages = self._grant(n)
         if pages is None:
             return None
         self._tables[rid] = pages
@@ -148,10 +152,11 @@ class PagedKVPool:
 
     def free(self, rid: int) -> int:
         """Release every page ``rid`` owns; returns how many came back."""
-        pages = self._tables.pop(rid)
-        del self._lens[rid]
-        self._free.extend(reversed(pages))  # LIFO: freed pages are reused first
-        self.frees += len(pages)
+        with obs_trace.span("pool:free", rid=rid, pages=len(self._tables[rid])):
+            pages = self._tables.pop(rid)
+            del self._lens[rid]
+            self._free.extend(reversed(pages))  # LIFO: freed pages are reused first
+            self.frees += len(pages)
         return len(pages)
 
     # ----------------------------------------------------------- tables
@@ -167,7 +172,12 @@ class PagedKVPool:
 
     def table(self, slot_rids: list[int | None], width: int) -> np.ndarray:
         """(B, width) page table for a batch of slots (``None`` = free slot)."""
-        return np.stack([self.table_row(r, width) for r in slot_rids])
+        live = [r for r in slot_rids if r is not None]
+        with obs_trace.span(
+            "pool:table", rows=len(live), used=sum(self._lens[r] for r in live),
+            reserved=self.page_size * sum(len(self._tables[r]) for r in live),
+        ):
+            return np.stack([self.table_row(r, width) for r in slot_rids])
 
     def kv_lens(self, slot_rids: list[int | None]) -> np.ndarray:
         return np.array(

@@ -386,7 +386,7 @@ def main(argv=None):
                 if orec is not None:
                     # attributed interval on the device timeline: the span
                     # the exporter aligns against control-plane spans
-                    orec.device_span(f"int{k}", t0, t1,
+                    orec.device_span("attr:interval", t0, t1,
                                      track=f"attr:{name}",
                                      value=led.total_energy_j)
         if n_dev:
@@ -486,39 +486,34 @@ def main(argv=None):
         k = sched.current_interval
         interval_occ[k] = n_marks
         _mark_fleet()
-        orec = obs_trace.active()
-        int_t0_us = obs_trace.now_us() if orec is not None else 0
-        for _ in range(max(args.steps_per_sync, 1)):
-            if not sched.live_rids:
-                break
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32) % cfg.vocab_size
-            if paged:
-                # per-slot ragged state from the pool: draining/free slots
-                # decode as kv_len == 0 padding (exact-zero attention)
-                live_set = set(sched.live_rids)
-                slot_r = [r if r in live_set else None for r in sched.slot_rids]
-                table = jnp.asarray(pool.table(slot_r, table_width))
-                lens = jnp.asarray(pool.kv_lens(slot_r))
-                live_m = jnp.asarray([r is not None for r in slot_r])
-                logits, pcache = decode_paged(
-                    params, pcache, tok, table, lens, live_m
-                )
-                for r in slot_r:
-                    if r is not None:
-                        assert pool.append(r), "reservation covers the generation"
-            else:
-                logits, cache = decode(params, cache, tok)
-            rec = sched.step_billing(1)
-            _sweep_pool()
-            telemetry.record_step(step_count, 0.0, b)
-            step_count += 1
-            billed_tokens += rec.billed_tokens
-            decoded_tokens += rec.decoded_tokens
-        sealed = sched.seal_interval()
-        if orec is not None and sealed is not None:
-            orec.span_at(f"interval {sealed.index}", int_t0_us,
-                         obs_trace.now_us(), track="serve",
-                         value=float(sealed.decoded_tokens))
+        with obs_trace.span("serve:interval", interval=k):
+            for _ in range(max(args.steps_per_sync, 1)):
+                if not sched.live_rids:
+                    break
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32) % cfg.vocab_size
+                if paged:
+                    # per-slot ragged state from the pool: draining/free slots
+                    # decode as kv_len == 0 padding (exact-zero attention)
+                    live_set = set(sched.live_rids)
+                    slot_r = [r if r in live_set else None for r in sched.slot_rids]
+                    table = jnp.asarray(pool.table(slot_r, table_width))
+                    lens = jnp.asarray(pool.kv_lens(slot_r))
+                    live_m = jnp.asarray([r is not None for r in slot_r])
+                    logits, pcache = decode_paged(
+                        params, pcache, tok, table, lens, live_m
+                    )
+                    for r in slot_r:
+                        if r is not None:
+                            assert pool.append(r), "reservation covers the generation"
+                else:
+                    logits, cache = decode(params, cache, tok)
+                rec = sched.step_billing(1)
+                _sweep_pool()
+                telemetry.record_step(step_count, 0.0, b)
+                step_count += 1
+                billed_tokens += rec.billed_tokens
+                decoded_tokens += rec.decoded_tokens
+            sealed = sched.seal_interval()
         if sealed is None:
             interval_occ.pop(k, None)
             continue

@@ -22,7 +22,12 @@ Instrumented call sites follow the pattern::
         rec.counter("rx.frames", float(n), track="rx")
 
 which costs one module-attribute read and an ``is None`` test when
-tracing is disabled (the default).
+tracing is disabled (the default).  The serving path's parts wrap their
+work in a program span instead, which also lands in any JAX profiler
+trace::
+
+    with trace.span("sched:step", live=n_live, slots=n_slots):
+        ...
 """
 
 from __future__ import annotations

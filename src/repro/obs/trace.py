@@ -15,13 +15,23 @@ Design goals, in order:
    intervals) live on the virtual device clock, in seconds.  Recorded
    ``anchor`` pairs let the exporter shift device-time tracks onto the
    wall timeline so one Perfetto view aligns both.
+4. **One span call, two sinks.**  :func:`span` is what the program's
+   parts (scheduler, KV pool, sensor fleet, attribution) call around
+   their work.  It opens a profiler TraceMe whenever JAX is loaded, so
+   the span lands in any JAX profiler trace on the device trace's clock
+   with its metadata as the event's stats; with a recorder installed it
+   also writes the span into the ring.  Span names come from a fixed
+   set: indices and ids travel as metadata, never in the name, so the
+   name table stays bounded however long a server runs.
 
 Only numpy + stdlib may be imported here: ``repro.core.host`` and
-``repro.stream.fleet`` import this module from their hot paths.
+``repro.stream.fleet`` import this module from their hot paths.  JAX is
+looked up in ``sys.modules`` when a span opens, never imported.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -40,6 +50,7 @@ __all__ = [
     "uninstall",
     "active",
     "now_us",
+    "span",
 ]
 
 # event kinds
@@ -82,25 +93,37 @@ class TraceEvent:
 
 
 class _Span:
-    """Context manager recording a wall-clock span on exit."""
+    """Context manager recording a wall-clock span on exit, inside a
+    profiler TraceMe ``me`` when one is given (:func:`span`)."""
 
-    __slots__ = ("_rec", "_name", "_track", "_value", "_t0")
+    __slots__ = ("_rec", "_name", "_track", "_value", "_t0", "_me")
 
-    def __init__(self, rec: "TraceRecorder", name: str, track: str, value: float):
+    def __init__(self, rec: "TraceRecorder", name: str, track: str, value: float,
+                 me=None):
         self._rec = rec
         self._name = name
         self._track = track
         self._value = value
+        self._me = me
 
     def __enter__(self) -> "_Span":
         self._t0 = now_us()
+        if self._me is not None:
+            self._me.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._me is not None:
+            self._me.__exit__(*exc)
         t1 = now_us()
         self._rec.span_at(
             self._name, self._t0, t1, track=self._track, value=self._value
         )
+
+    def set_metadata(self, **meta) -> None:
+        """Add metadata known only once the work is done (profiler only)."""
+        if self._me is not None:
+            self._me.set_metadata(**meta)
 
 
 class TraceRecorder:
@@ -323,3 +346,46 @@ def uninstall() -> TraceRecorder | None:
 def active() -> TraceRecorder | None:
     """The installed recorder, or None when tracing is disabled."""
     return _active
+
+
+# -- program spans: one call, two sinks -------------------------------------
+
+
+class _NoSpan:
+    """What :func:`span` returns when neither the profiler nor a recorder
+    can take the span."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **meta) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **meta):
+    """Context manager: a program span named ``name`` with numeric ``meta``.
+
+    The span goes to the JAX profiler (``jax.profiler.TraceAnnotation``,
+    ``meta`` as its keywords) whenever JAX is loaded; with the profiler
+    off that costs about a microsecond.  With a recorder installed it
+    also lands in the ring, on the track named by ``name``'s prefix
+    (``"sched:admit"`` -> ``"sched"``), with the first ``meta`` value as
+    its ``value``.  The object bound by ``with`` takes more metadata for
+    the profiler through ``set_metadata(**meta)``.  ``name`` is one of a
+    fixed set (PERF.md, section 3): never put an index or an id in it.
+    """
+    jax = sys.modules.get("jax")
+    me = jax.profiler.TraceAnnotation(name, **meta) if jax is not None else None
+    rec = _active
+    if rec is None:
+        return _NO_SPAN if me is None else me
+    return _Span(rec, name, name.partition(":")[0],
+                 float(next(iter(meta.values()), 0.0)), me)

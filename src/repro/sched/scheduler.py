@@ -388,39 +388,43 @@ class ContinuousBatch(_SloCore):
         ]
         if not self.queue or not reusable:
             return []
-        ctx = self._context(now_s)
-        order = self.policy.order(self.queue, ctx)
-        limit = min(self.policy.batch_limit(self.queue, ctx), self.n_slots)
-        room = limit - self.n_active
-        admitted: list[tuple[int, Request]] = []
-        predicted = 0.0
-        remaining = self.remaining_budget_j
-        chosen: list[Request] = []
-        for qi in order:
-            if len(chosen) >= min(room, len(reusable)):
-                break
-            req = self.queue[qi]
-            price = self.pricer.price_tokens(req.gen_len - req.done_tokens)
-            if predicted + price > remaining:
-                continue
-            chosen.append(req)
-            predicted += price
-        for slot, req in zip(reusable, chosen):
-            self.queue.remove(req)
-            price = self.pricer.price_tokens(req.gen_len - req.done_tokens)
-            req.predicted_j = price
-            req.committed_j = price
-            req.committed_tokens = max(req.gen_len - req.done_tokens, 0)
-            self.committed_j += price
-            self.slot_rids[slot] = req.rid
-            self.slot_states[slot] = SLOT_ACTIVE
-            admitted.append((slot, req))
-        if not admitted and room > 0 and not (self.committed_j or self.inflight_j):
-            self._reject_hopeless()
+        with obs_trace.span("sched:admit", queued=len(self.queue)) as sp:
+            ctx = self._context(now_s)
+            order = self.policy.order(self.queue, ctx)
+            limit = min(self.policy.batch_limit(self.queue, ctx), self.n_slots)
+            room = limit - self.n_active
+            admitted: list[tuple[int, Request]] = []
+            predicted = 0.0
+            remaining = self.remaining_budget_j
+            chosen: list[Request] = []
+            for qi in order:
+                if len(chosen) >= min(room, len(reusable)):
+                    break
+                req = self.queue[qi]
+                price = self.pricer.price_tokens(req.gen_len - req.done_tokens)
+                if predicted + price > remaining:
+                    continue
+                chosen.append(req)
+                predicted += price
+            for slot, req in zip(reusable, chosen):
+                self.queue.remove(req)
+                price = self.pricer.price_tokens(req.gen_len - req.done_tokens)
+                req.predicted_j = price
+                req.committed_j = price
+                req.committed_tokens = max(req.gen_len - req.done_tokens, 0)
+                self.committed_j += price
+                self.slot_rids[slot] = req.rid
+                self.slot_states[slot] = SLOT_ACTIVE
+                admitted.append((slot, req))
+                # the wait ends here, so it travels as metadata of a point span
+                with obs_trace.span("sched:queue", rid=req.rid,
+                                    wait_ms=(now_s - req.arrival_s) * 1e3,
+                                    prompt_len=req.prompt_len):
+                    pass
+            if not admitted and room > 0 and not (self.committed_j or self.inflight_j):
+                self._reject_hopeless()
+            sp.set_metadata(admitted=len(admitted))
         if admitted:
-            rec = obs_trace.active()
-            if rec is not None:
-                rec.instant("sched:admit", track="sched", value=float(len(admitted)))
             reg = obs_metrics.active()
             if reg is not None:
                 reg.counter("sched_admitted_total", "requests admitted").inc(
@@ -444,44 +448,47 @@ class ContinuousBatch(_SloCore):
         the fixed compiled batch shape ran them, and the pricer's
         correction must price what the hardware actually did.
         """
-        rids: list[int] = []
-        tokens: list[int] = []
-        for slot, (rid, state) in enumerate(
-            zip(self.slot_rids, self.slot_states)
-        ):
-            if state != SLOT_ACTIVE or rid is None:
-                continue
-            req = self._by_rid[rid]
-            d = min(int(slot_tokens), max(req.gen_len - req.done_tokens, 0))
-            if d > 0:
-                move = (
-                    req.committed_j * d / req.committed_tokens
-                    if req.committed_tokens > 0
-                    else 0.0
-                )
-                req.committed_j -= move
-                req.committed_tokens -= d
-                self.committed_j -= move
-                self.inflight_j += move
-                self._cur.predicted_j += move
-                self._cur.occupancy[rid] = self._cur.occupancy.get(rid, 0) + d
-                req.done_tokens += d
-                rids.append(rid)
-                tokens.append(d)
-            if req.done_tokens >= req.gen_len:
-                self._finish(req, slot)
-        n_decoded = self.n_slots if decoded_slots is None else int(decoded_slots)
-        decoded = int(slot_tokens) * n_decoded
-        self._cur.steps += 1
-        self._cur.decoded_tokens += decoded
-        rec = StepRecord(
-            index=len(self.steps),
-            interval=self._cur.index,
-            rids=tuple(rids),
-            tokens=tuple(tokens),
-            decoded_tokens=decoded,
-        )
-        self.steps.append(rec)
+        with obs_trace.span("sched:step", live=len(self.live_rids),
+                            slots=self.n_slots) as sp:
+            rids: list[int] = []
+            tokens: list[int] = []
+            for slot, (rid, state) in enumerate(
+                zip(self.slot_rids, self.slot_states)
+            ):
+                if state != SLOT_ACTIVE or rid is None:
+                    continue
+                req = self._by_rid[rid]
+                d = min(int(slot_tokens), max(req.gen_len - req.done_tokens, 0))
+                if d > 0:
+                    move = (
+                        req.committed_j * d / req.committed_tokens
+                        if req.committed_tokens > 0
+                        else 0.0
+                    )
+                    req.committed_j -= move
+                    req.committed_tokens -= d
+                    self.committed_j -= move
+                    self.inflight_j += move
+                    self._cur.predicted_j += move
+                    self._cur.occupancy[rid] = self._cur.occupancy.get(rid, 0) + d
+                    req.done_tokens += d
+                    rids.append(rid)
+                    tokens.append(d)
+                if req.done_tokens >= req.gen_len:
+                    self._finish(req, slot)
+            n_decoded = self.n_slots if decoded_slots is None else int(decoded_slots)
+            decoded = int(slot_tokens) * n_decoded
+            self._cur.steps += 1
+            self._cur.decoded_tokens += decoded
+            rec = StepRecord(
+                index=len(self.steps),
+                interval=self._cur.index,
+                rids=tuple(rids),
+                tokens=tuple(tokens),
+                decoded_tokens=decoded,
+            )
+            self.steps.append(rec)
+            sp.set_metadata(billed=rec.billed_tokens)
         return rec
 
     def _release_commitment(self, req: Request) -> None:
@@ -539,14 +546,10 @@ class ContinuousBatch(_SloCore):
         if self._cur.steps == 0:
             return None
         sealed = self._cur
-        self.intervals.append(sealed)
-        self._cur = IntervalRecord(index=sealed.index + 1)
-        rec = obs_trace.active()
-        if rec is not None:
-            rec.instant(
-                f"sched:seal interval={sealed.index}", track="sched",
-                value=float(sealed.decoded_tokens),
-            )
+        with obs_trace.span("sched:seal", interval=sealed.index,
+                            decoded=sealed.decoded_tokens):
+            self.intervals.append(sealed)
+            self._cur = IntervalRecord(index=sealed.index + 1)
         reg = obs_metrics.active()
         if reg is not None:
             reg.counter("sched_intervals_sealed_total", "step intervals sealed").inc()
@@ -555,27 +558,22 @@ class ContinuousBatch(_SloCore):
     def _settle(self, rec: IntervalRecord, energy_j: float, from_measurement: bool) -> None:
         if rec.measured_j is not None:
             raise ValueError(f"interval {rec.index} already settled")
-        rec.measured_j = float(energy_j)
-        rec.released = not from_measurement
-        self.inflight_j -= rec.predicted_j
-        self.spent_j += rec.measured_j
-        if rec.occupancy:
-            self._split_settled(
-                list(rec.occupancy), list(rec.occupancy.values()), rec.measured_j
-            )
-        else:
-            # the hardware drew power but no live request occupied a slot
-            # (all padding): surfaced as overhead, never silently dropped
-            self.overhead_j += rec.measured_j
-        if from_measurement and rec.decoded_tokens:
-            self.pricer.update(rec.decoded_tokens, rec.measured_j)
-        trec = obs_trace.active()
-        if trec is not None:
-            trec.instant(
-                f"sched:{'settle' if from_measurement else 'release'}"
-                f" interval={rec.index}",
-                track="sched", value=rec.measured_j,
-            )
+        with obs_trace.span("sched:settle", interval=rec.index,
+                            measured=int(from_measurement)):
+            rec.measured_j = float(energy_j)
+            rec.released = not from_measurement
+            self.inflight_j -= rec.predicted_j
+            self.spent_j += rec.measured_j
+            if rec.occupancy:
+                self._split_settled(
+                    list(rec.occupancy), list(rec.occupancy.values()), rec.measured_j
+                )
+            else:
+                # the hardware drew power but no live request occupied a slot
+                # (all padding): surfaced as overhead, never silently dropped
+                self.overhead_j += rec.measured_j
+            if from_measurement and rec.decoded_tokens:
+                self.pricer.update(rec.decoded_tokens, rec.measured_j)
         reg = obs_metrics.active()
         if reg is not None:
             reg.counter(

@@ -443,9 +443,10 @@ class FleetMonitor:
     # ------------------------------------------------------------ sim helpers
     def advance(self, dt_s: float) -> None:
         """Advance every (virtual) device's clock and drain it."""
-        for ps in self._sensors.values():
-            ps.device.advance(dt_s)
-        self.poll_all()
+        with obs_trace.span("fleet:advance", devices=len(self._sensors)):
+            for ps in self._sensors.values():
+                ps.device.advance(dt_s)
+            self.poll_all()
 
     def run_for(self, seconds: float, chunk_s: float = 0.5) -> None:
         remaining = seconds
@@ -456,8 +457,9 @@ class FleetMonitor:
 
     # ------------------------------------------------------------ markers
     def mark_all(self, char: str = "M") -> None:
-        for ps in self._sensors.values():
-            ps.mark(char)
+        with obs_trace.span("fleet:mark", devices=len(self._sensors)):
+            for ps in self._sensors.values():
+                ps.mark(char)
 
     def _marker_time(self, ps: "PowerSensor", char: str, occurrence: int = 0) -> float | None:
         hits = [t for c, t in ps.markers if c == char]
@@ -487,32 +489,33 @@ class FleetMonitor:
         their own integration (e.g. `repro.attrib.attribute_block`) start
         here instead of reaching into the ring and lock directly.
         """
-        if char_b is None:
-            char_b = char_a
-        if occurrence_b is None:
-            occurrence_b = occurrence
-        ps = self._sensors[device]
-        # one pass over the (copied) marker list serves both lookups
-        hits_a = [t for c, t in ps.markers if c == char_a]
-        hits_b = hits_a if char_b == char_a else [t for c, t in ps.markers if c == char_b]
-        if occurrence >= len(hits_a) or occurrence_b >= len(hits_b):
-            return None
-        t0, t1 = hits_a[occurrence], hits_b[occurrence_b]
-        if t1 <= t0:
-            return None
-        block = self._locked_ring_read(ps, lambda: ps.ring.window(t0, t1))
-        if len(block) < 2:
-            return None
-        # evicted head: first retained frame starts well after t0.  The
-        # frame interval is estimated as the *median* inter-frame dt — the
-        # first two frames alone are unreliable exactly when it matters
-        # (a delivery gap at the window's leading edge inflates their dt,
-        # making this check too lenient and silently accepting a window
-        # that is missing its leading coverage)
-        frame_dt = float(np.median(np.diff(block.times_s)))
-        if block.times_s[0] - t0 > 2.0 * frame_dt:
-            return None
-        return t0, t1, block
+        with obs_trace.span("fleet:window", devices=len(self._sensors)):
+            if char_b is None:
+                char_b = char_a
+            if occurrence_b is None:
+                occurrence_b = occurrence
+            ps = self._sensors[device]
+            # one pass over the (copied) marker list serves both lookups
+            hits_a = [t for c, t in ps.markers if c == char_a]
+            hits_b = hits_a if char_b == char_a else [t for c, t in ps.markers if c == char_b]
+            if occurrence >= len(hits_a) or occurrence_b >= len(hits_b):
+                return None
+            t0, t1 = hits_a[occurrence], hits_b[occurrence_b]
+            if t1 <= t0:
+                return None
+            block = self._locked_ring_read(ps, lambda: ps.ring.window(t0, t1))
+            if len(block) < 2:
+                return None
+            # evicted head: first retained frame starts well after t0.  The
+            # frame interval is estimated as the *median* inter-frame dt — the
+            # first two frames alone are unreliable exactly when it matters
+            # (a delivery gap at the window's leading edge inflates their dt,
+            # making this check too lenient and silently accepting a window
+            # that is missing its leading coverage)
+            frame_dt = float(np.median(np.diff(block.times_s)))
+            if block.times_s[0] - t0 > 2.0 * frame_dt:
+                return None
+            return t0, t1, block
 
     def marker_windows(
         self,

@@ -102,6 +102,74 @@ def test_trace_install_uninstall_active():
     assert obs_trace.active() is None and obs_trace.uninstall() is None
 
 
+# ----------------------------------------------------------- program spans
+def test_program_span_without_a_sink_records_nothing():
+    """No recorder installed, no profiler running: a no-op that raises nothing."""
+    idle = TraceRecorder(capacity=4)  # built, never installed
+    with obs_trace.span("sched:admit", queued=3) as sp:
+        sp.set_metadata(admitted=2)
+    assert obs_trace.active() is None and idle.head == 0
+
+
+def test_program_span_writes_one_ring_event_with_a_bare_name():
+    rec = obs_trace.install(TraceRecorder(capacity=16))
+    with obs_trace.span("pool:alloc", rid=7, pages=3) as sp:
+        sp.set_metadata(granted=3)
+    (ev,) = rec.events()
+    assert ev.kind == SPAN and ev.clock == WALL
+    # metadata in value, never in the name; the track is the name's prefix
+    assert ev.name == "pool:alloc" and ev.track == "pool" and ev.value == 7.0
+
+
+def test_trace_module_imports_numpy_and_stdlib_only():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from repro.obs import trace\n"
+            "with trace.span('sched:step', live=1, slots=2):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'trace pulled in jax'\n")
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+
+
+def test_name_table_stays_bounded_over_many_intervals():
+    """Interval indices and request ids travel as values, so a long-running
+    server does not grow the recorder's name table."""
+    from repro.kernels.paged_attention import PagedKVPool
+    from repro.sched import ContinuousBatch, EnergyPricer, Request, get_policy
+
+    rec = obs_trace.install(TraceRecorder(capacity=256))
+    sched = ContinuousBatch(
+        EnergyPricer(j_per_token=1.0), get_policy("throughput-max"), n_slots=2
+    )
+    pool = PagedKVPool(n_pages=8, page_size=4)
+    sizes = []
+    for i in range(1000):
+        sched.submit(Request(rid=i, gen_len=1, arrival_s=float(i)))
+        for _, req in sched.admit(float(i) + 0.5):
+            pool.alloc(req.rid, 3)
+        pool.table(sched.slot_rids, 2)
+        sched.step_billing(1)
+        pool.free(i)
+        sealed = sched.seal_interval()
+        if i % 2:
+            sched.settle_interval(sealed.index, 1.0)
+        else:
+            sched.release_interval(sealed.index)
+        sizes.append(len(rec._names))
+    assert sizes[-1] == sizes[1] == len(set(rec._names))
+    assert set(rec._names) == {"sched:admit", "sched:queue", "pool:alloc", "pool:table",
+                               "sched:step", "pool:free", "sched:seal", "sched:settle"}
+    assert rec.dropped > 0  # the ring wrapped; the name table did not grow
+
+
 # --------------------------------------------------------------- metrics
 def test_counter_monotonic():
     c = Counter()
@@ -326,9 +394,12 @@ def test_scheduler_emits_admission_and_settlement_series():
     assert reg.get_value("sched_intervals_settled_total", mode="measured") == 1.0
     assert reg.get_value("sched_settled_joules_total") == 10.0
     names = {e.name for e in rec.events()}
-    assert "sched:admit" in names
-    assert f"sched:seal interval={sealed.index}" in names
-    assert f"sched:settle interval={sealed.index}" in names
+    assert {"sched:admit", "sched:queue", "sched:step", "sched:seal", "sched:settle"} <= names
+    assert all(e.track == "sched" for e in rec.events())
+    # ids and indices travel as values, never in the name
+    assert sorted(e.value for e in rec.events_named("sched:queue")) == [0.0, 1.0]
+    assert [e.value for e in rec.events_named("sched:seal")] == [float(sealed.index)]
+    assert [e.value for e in rec.events_named("sched:settle")] == [float(sealed.index)]
 
 
 def test_governor_emits_tick_metrics():
