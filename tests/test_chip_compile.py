@@ -70,19 +70,32 @@ def _hbm_bytes(compiled) -> int:
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+#: (configuration, rows, table width): the serve smoke's, and the benchmark's
+#: chat (32 slots of 161 pages) and batch-long (3 slots of 113) cells
+SMOKE = ("qwen2.5-3b", B, TABLE)
+
+
+@pytest.mark.parametrize("dtype,shape", [
+    pytest.param(jnp.bfloat16, SMOKE, id="bfloat16"),
+    pytest.param(jnp.float32, SMOKE, id="float32"),
+    pytest.param(jnp.bfloat16, ("phi3-mini", B, TABLE), id="bfloat16-phi3-mini"),
+    pytest.param(jnp.bfloat16, ("qwen2.5-3b", 32, 161), id="bfloat16-chat"),
+    pytest.param(jnp.bfloat16, ("phi3-mini", 3, 113), id="bfloat16-batch-long"),
+])
 @pytest.mark.parametrize("bk", [None, 8])
-def test_paged_kernel_compiles_at_qwen_widths(one_chip, dtype, bk):
-    cfg = get_config("qwen2.5-3b")
+def test_paged_kernel_compiles_at_qwen_widths(one_chip, dtype, shape, bk):
+    arch, rows, table = shape
+    cfg = get_config(arch)
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    n_pages = 1 + rows * table
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     compiled = jax.jit(
         lambda q, k, v, t, n: paged_decode_attention_pallas(
             q, k, v, t, n, bk=bk, interpret=False)
     ).lower(
-        sds((B, hq, d), dtype), sds((hkv, N_PAGES, PAGE, d), dtype),
-        sds((hkv, N_PAGES, PAGE, d), dtype), sds((B, TABLE), jnp.int32),
-        sds((B,), jnp.int32),
+        sds((rows, hq, d), dtype), sds((hkv, n_pages, PAGE, d), dtype),
+        sds((hkv, n_pages, PAGE, d), dtype), sds((rows, table), jnp.int32),
+        sds((rows,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

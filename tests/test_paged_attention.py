@@ -68,20 +68,29 @@ def _build_paged(rng, kv_lens, ps, max_pages, hkv, d, dtype):
 # --------------------------------------------------------------------------- kernel
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("shape", [
-    # (ps, max_pages, Hq, Hkv, D, kv_lens) — incl. 0, 1, ragged, exactly full
-    (16, 4, 4, 4, 64, (0, 1, 37, 64)),
-    (32, 2, 8, 2, 64, (0, 33, 64)),    # GQA group 4
-    (8, 3, 4, 1, 128, (24, 5)),        # MQA, exact page multiple
+    # (ps, max_pages, Hq, Hkv, D, kv_lens, bk) — incl. 0, 1, ragged, exactly full
+    (16, 4, 4, 4, 64, (0, 1, 37, 64), None),
+    (32, 2, 8, 2, 64, (0, 33, 64), None),    # GQA group 4
+    (8, 3, 4, 1, 128, (24, 5), None),        # MQA, exact page multiple
+    # chunks of 3 pages over a 7-page table (the last chunk part past it),
+    # qwen2.5-3b's heads: kv_len 0, 1, ps-1, ps, a chunk -1/+0/+1, full table
+    (16, 7, 16, 2, 128, (0, 1, 15, 16, 47, 48, 49, 112), 48),
+    # phi3-mini's heads (group 1, 32 KV heads of 96), dead rows between live
+    # ones, chunks of 2 pages over 5
+    (16, 5, 32, 32, 96, (0, 1, 15, 0, 16, 31, 32, 33, 80), 32),
+    # group 1 at a lane-aligned head width (the manual-DMA walk)
+    (16, 5, 8, 8, 128, (33, 0, 0, 16, 80, 0, 47), 32),
 ])
 def test_paged_decode_matches_oracles(shape, dtype):
-    ps, max_pages, hq, hkv, d, kv_lens = shape
+    ps, max_pages, hq, hkv, d, kv_lens, bk = shape
     rng = np.random.default_rng(sum(kv_lens) + ps)
     _, kp, vp, table, lens, kd, vd = _build_paged(
         rng, kv_lens, ps, max_pages, hkv, d, dtype
     )
     b = len(kv_lens)
     q = _rand(jax.random.PRNGKey(0), (b, hq, d), dtype)
-    out = paged_decode_attention(q, kp, vp, table, lens)
+    out = paged_decode_attention(q, kp, vp, table, lens, bk=bk)
+    assert (np.asarray(out)[np.asarray(lens) == 0] == 0.0).all()
     tol = TOL32 if dtype == jnp.float32 else TOL
     np.testing.assert_allclose(
         np.asarray(out, np.float32),
