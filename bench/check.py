@@ -2,13 +2,13 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the one
-with the most served tokens, goes through the float32 reference
-(`bench.reference.model`) with its prompt and served tokens. For each
-served token the gap is the reference's best logit minus the logit of
-the token served there; greedy serving reads 0 where it agrees with the
-reference. The widest gap is held against the cell's limit
-(``bench/limits/<workload>.json``), set in PERF.md from the program's
-sound runs and from the fp8 control.
+with the most served tokens, goes through the float32 reference of the
+configuration's model module (``logits_at``, `bench.models`) with its
+prompt and served tokens. For each served token the gap is the
+reference's best logit minus the logit of the token served there; greedy
+serving reads 0 where it agrees with the reference. The widest gap is
+held against the cell's limit (``bench/limits/<workload>.json``), set in
+PERF.md from the program's sound runs and from the fp8 control.
 
 Training (`judge_train`): the program's first steps against the float32
 reference's from the same weights and batches: each step's loss, and by
@@ -69,8 +69,9 @@ def logit_gaps(cfg: dict, seed: int, reqs: list, chosen=None, quant=None) -> np.
     """Per served token: the float32 reference's best logit minus the logit of
     ``chosen`` (the served tokens when None). With ``quant`` the chosen token
     is instead what the quantised reference ranks first at each position."""
-    from bench.reference.model import logits_at
+    from bench import models
 
+    logits_at = models.load(cfg).logits_at
     tokens, rows, served = pack_rows(reqs)
     with jax.default_matmul_precision("highest"):
         ref = logits_at(cfg, seed, jnp.asarray(tokens), jnp.asarray(rows))

@@ -15,7 +15,9 @@ length. ``attempted`` counts the steps started in the window; one whose
 loss is not finite fails. Once the window has closed and the program's
 state is freed, the float32 reference (`bench.reference.train`) follows
 the first steps from the same weights and batches on the same chips, and
-`bench.check.judge_train` compares.
+`bench.check.judge_train` compares. Weights, change norms and the
+reference all come through the configuration's model module
+(`bench.models`).
 """
 from __future__ import annotations
 
@@ -40,10 +42,10 @@ def _norms(tree):
 
 
 def run(ctx) -> dict:
-    from bench import check
-    from bench.reference import train as reference
+    from bench import check, models
+    from bench.reference.train import spread, spread_leaf
     from bench.traffic import train_batches
-    from bench.weights import arch_config, change_norms, make_params
+    from bench.weights import change_norms, make_params
     from repro.configs import RunConfig
     from repro.launch import mesh as mesh_lib
     from repro.models import build_model
@@ -51,7 +53,9 @@ def run(ctx) -> dict:
     from repro.train import step as train_step
 
     mix, seed = ctx.mix, ctx.seed
-    cfg = arch_config(ctx.cfg)
+    module = models.load(ctx.cfg)
+    leaves = module.leaves(ctx.cfg)
+    cfg = module.arch_config(ctx.cfg)
     model = build_model(cfg, RunConfig(attn_impl=mix["attn_impl"], remat=mix["remat"]))
     mesh = mesh_lib.make_mesh(tuple(mix["mesh"]), ("data", "model"))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -63,7 +67,7 @@ def run(ctx) -> dict:
     opt_cfg = AdamWConfig(**mix["adamw"])
     step = jax.jit(train_step.make_train_step(model, opt_cfg), donate_argnums=(0, 1),
                    in_shardings=(p_sh, o_sh, b_sh), out_shardings=(p_sh, o_sh, None))
-    params = make_params(model, seed, jnp.float32, p_sh)
+    params = make_params(model, seed, leaves, jnp.float32, p_sh)
     opt = jax.jit(init_opt_state, out_shardings=o_sh)(params)
     feed = [jax.device_put({"tokens": x}, b_sh) for x in batches]
 
@@ -75,7 +79,7 @@ def run(ctx) -> dict:
         if i == 0:
             prog["grad_norms"] = {p: float(x) / (1 - opt_cfg.b1)
                                   for p, x in _by_path(_norms(opt["m"])).items()}
-    prog["change_norms"] = change_norms(_by_path(params), seed, cfg.n_layers)
+    prog["change_norms"] = change_norms(_by_path(params), seed, leaves)
 
     trace = None
     if ctx.trace:
@@ -113,8 +117,8 @@ def run(ctx) -> dict:
     del params, opt, met, pending, feed, step
 
     devices = list(mesh.devices.flat)
-    ref = reference.follow(ctx.cfg, seed, reference.spread(devices, batches[:n_check]),
-                           dict(mix["adamw"]), sharding=reference.spread_leaf(devices))
+    ref = module.follow(ctx.cfg, seed, spread(devices, batches[:n_check]),
+                        dict(mix["adamw"]), sharding=spread_leaf(devices))
     verdict = check.judge_train(prog, ref, ctx.limits)
     return {"attempted": len(done), "failed": failed,
             "metrics": {"train_tok_s": in_window * tokens_per_step / ctx.seconds,

@@ -1,7 +1,7 @@
 """The decode step's share of the chip's bf16 peak, taken whole, in %.
 
 Operations the step needs (2 per weight per live token, plus attention
-over each row's live ``kv_len``; `bench.work.decode_step_flops`) over the
+over each row's live ``kv_len``: the model module's ``decode_step_flops``) over the
 device time of the decode program times the peak. Padded slots and
 recomputation count for nothing.
 """

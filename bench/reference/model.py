@@ -98,7 +98,7 @@ def block(x, w, c, quant=None):
     """
     cfg = dict(c)
     hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg["hidden_size"] // hq
+    hd = cfg["head_dim"]
     eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
     a = _rmsnorm(x, w["ln1"], eps)
     q = _mm(a, w["wq"], quant)
@@ -131,10 +131,12 @@ def _head(x, final_norm, head, c, quant):
 
 
 def frozen(cfg: dict):
-    """The configuration keys the reference reads, hashable for `jax.jit`."""
+    """The configuration keys the reference reads, hashable for `jax.jit`;
+    ``head_dim`` is ``hidden_size // num_attention_heads`` where not given."""
     keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "rms_norm_eps", "rope_theta", "vocab_size")
-    return tuple((k, cfg[k]) for k in keys)
+    hd = cfg.get("head_dim", cfg["hidden_size"] // cfg["num_attention_heads"])
+    return tuple((k, cfg[k]) for k in keys) + (("head_dim", hd),)
 
 
 def logits_at(cfg: dict, seed: int, tokens, rows, quant: str | None = None):

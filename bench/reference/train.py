@@ -24,7 +24,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from bench.weights import PROGRAM_LEAVES, change_norms
+from bench import models
+from bench.weights import change_norms
 
 from . import adapter
 from .model import _mm, _rmsnorm, block, frozen
@@ -51,12 +52,14 @@ def spread(devices: list, batches) -> list:
     return [jax.device_put(b, place((b.shape[0], 1))) for b in batches]
 
 
-def loss(w: dict, tokens, c, quant=None):
-    """Mean next-token cross-entropy of ``tokens`` (B, S+1) under weights ``w``."""
+def loss(w: dict, tokens, c, names, quant=None):
+    """Mean next-token cross-entropy of ``tokens`` (B, S+1) under weights ``w``
+    (by program path); ``names``: (path, reference name, per layer) of each."""
     cfg = dict(c)
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    layers = {ref: w[p] for p, ref, layered in names if layered}
+    w = {ref: w[p] for p, ref, layered in names if not layered}
     x = w["embed"][inputs]
-    layers = {PROGRAM_LEAVES[p]: a for p, a in w.items() if p.startswith("layers/")}
 
     @jax.checkpoint
     def body(h, lw):
@@ -80,11 +83,11 @@ def lr_at(opt: dict, step: int) -> float:
     return opt["lr"] * warm * (lo + (1 - lo) * 0.5 * (1 + math.cos(math.pi * frac)))
 
 
-@partial(jax.jit, static_argnames=("c", "quant", "opt"), donate_argnums=(0, 1, 2))
-def _step(w, m, v, tokens, lr, t, c, quant, opt):
+@partial(jax.jit, static_argnames=("c", "names", "quant", "opt"), donate_argnums=(0, 1, 2))
+def _step(w, m, v, tokens, lr, t, c, names, quant, opt):
     o = dict(opt)
     b1, b2 = o["b1"], o["b2"]
-    value, g = jax.value_and_grad(loss)(w, tokens, c, quant)
+    value, g = jax.value_and_grad(loss)(w, tokens, c, names, quant)
     norm = jnp.sqrt(sum(jnp.sum(x * x) for x in g.values()))
     scale = jnp.minimum(1.0, o["clip_norm"] / (norm + 1e-6)) if o["clip_norm"] else 1.0
     g = {p: x * scale for p, x in g.items()}
@@ -108,6 +111,8 @@ def follow(cfg: dict, seed: int, batches: list, opt: dict, quant=None, sharding=
     and ``change_norms`` (per leaf, after the last step).
     """
     c, o = frozen(cfg), tuple(sorted(opt.items()))
+    leaves = models.load(cfg).leaves(cfg)
+    names = tuple(sorted((p, leaf.ref, bool(leaf.layers)) for p, leaf in leaves.items()))
     w = adapter.train_weights(cfg, seed, sharding)
     m = jax.tree.map(jnp.zeros_like, w)
     v = jax.tree.map(jnp.zeros_like, w)
@@ -115,10 +120,10 @@ def follow(cfg: dict, seed: int, batches: list, opt: dict, quant=None, sharding=
     with jax.default_matmul_precision("highest"):
         for i, tokens in enumerate(batches):
             w, m, v, value, gn = _step(w, m, v, tokens, jnp.float32(lr_at(opt, i)),
-                                       jnp.float32(i + 1), c, quant, o)
+                                       jnp.float32(i + 1), c, names, quant, o)
             losses.append(float(value))
             if first is None:
                 first = {p: float(x) for p, x in gn.items()}
     del m, v
     return {"losses": losses, "grad_norms": first,
-            "change_norms": change_norms(w, seed, cfg["num_hidden_layers"])}
+            "change_norms": change_norms(w, seed, leaves)}

@@ -3,7 +3,8 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from ``BENCHMARK.json``: the cell's
-configuration (``bench/configs/<config>.json``), its traffic mix
+configuration (``bench/configs/<config>.json``) and the model module it
+names (``bench/models/<model>.py``), its traffic mix
 (``bench/traffic/<traffic>.json``), whose ``driver`` names
 ``bench/drivers/<driver>.py``, its correctness limits
 (``bench/limits/<workload>.json``) and, with ``--trace 1``, a reader per
@@ -105,13 +106,18 @@ def main(argv=None) -> int:
     try:
         with open(ROOT / "BENCHMARK.json") as fh:
             bench = json.load(fh)
-        from bench import check, work
+        from bench import check, models, work
         from bench.traffic import load_mix
         from bench.weights import load_config
         from repro.compile_cache import place_compile_cache, place_tpu_logs
     except (ImportError, OSError) as e:
         fail(f"cannot load the benchmark or the program: {e}", 2)
     w, _ = cell_of(bench, args.workload)
+    try:
+        cfg = load_config(w["config"])
+        models.load(cfg)
+    except (ImportError, OSError, KeyError) as e:
+        fail(f"cannot load the cell's configuration or its model: {e}", 2)
 
     place_tpu_logs()
     place_compile_cache()
@@ -124,7 +130,7 @@ def main(argv=None) -> int:
         fail(f"the cell needs {w['chips']} TPU chip(s); JAX found {len(devices)} "
              f"{devices[0].platform} device(s)")
     used = devices[: int(w["chips"])]
-    line = measure(bench, args.workload, load_config(w["config"]), load_mix(w["traffic"]),
+    line = measure(bench, args.workload, cfg, load_mix(w["traffic"]),
                    check.load_limits(args.workload), args.seed, args.seconds,
                    bool(args.trace), used, work.peaks(used[0].device_kind))
     print(json.dumps(line), flush=True)
@@ -138,6 +144,7 @@ def measure(bench: dict, workload: str, cfg: dict, mix: dict, limits: dict, seed
     Everything after the look for chips: the driver's set-up, window and
     check, the trace's reduction and the per-layer readers.
     """
+    from bench import models
     from bench.serving import NAMES
     from bench.trace_reduce import reduce_trace
 
@@ -173,7 +180,8 @@ def measure(bench: dict, workload: str, cfg: dict, mix: dict, limits: dict, seed
         tw = res["trace"]
         red = reduce_trace(trace_dir)
         window_s = red.get("window_s") or tw.t_off - tw.t_on
-        m = SimpleNamespace(trace=red, tw=tw, cfg=cfg, peak=peak, window_s=window_s, names=NAMES)
+        m = SimpleNamespace(trace=red, tw=tw, cfg=cfg, model=models.load(cfg), peak=peak,
+                            window_s=window_s, names=NAMES)
         values = {}
         if red.get("devices"):
             for e in metrics_of(bench["per_layer"], workload):

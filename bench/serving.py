@@ -434,16 +434,16 @@ def run_cell(ctx, make_source, end_to_end) -> dict:
     """
     from functools import partial
 
-    from bench import check, work
-    from bench.weights import arch_config
+    from bench import check, models
 
-    cfg = arch_config(ctx.cfg)
+    module = models.load(ctx.cfg)
+    cfg = module.arch_config(ctx.cfg)
     source = make_source(cfg.vocab_size)
     from repro.launch.serve import SERVE_RUN
     from repro.models import build_model
 
-    params = make_params_for(build_model(cfg, SERVE_RUN), ctx.seed)
-    engine = Engine(cfg, ctx.mix, params, work=partial(work.decode_step_flops, ctx.cfg))
+    params = make_params_for(build_model(cfg, SERVE_RUN), ctx.seed, module.leaves(ctx.cfg))
+    engine = Engine(cfg, ctx.mix, params, work=partial(module.decode_step_flops, ctx.cfg))
     engine.warm_up(ctx.mix["prompt_len"]["values"])
     del params
     trace = None
@@ -472,10 +472,10 @@ def run_cell(ctx, make_source, end_to_end) -> dict:
             "trace": trace, "summary": summary}
 
 
-def make_params_for(model, seed: int):
+def make_params_for(model, seed: int, leaves: dict):
     from bench.weights import make_params
 
-    params = make_params(model, seed)
+    params = make_params(model, seed, leaves)
     jax.block_until_ready(params)
     return params
 

@@ -15,16 +15,22 @@ for p in (str(ROOT), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 #: published-config keys at smoke widths: GQA with QKV bias and a tied
-#: head (Qwen2-like), and MHA without bias, untied (Phi-3-like)
+#: head (Qwen2-like), MHA without bias, untied (Phi-3-like), and GQA whose
+#: heads are wider than hidden_size / heads (4 heads of 32 at d 64)
 SMOKE_CONFIGS = {
-    "gqa": {"name": "smoke-gqa", "hidden_size": 64, "intermediate_size": 128,
+    "gqa": {"name": "smoke-gqa", "model": "dense", "hidden_size": 64, "intermediate_size": 128,
             "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2,
             "vocab_size": 300, "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,
             "tie_word_embeddings": True, "qkv_bias": True},
-    "mha": {"name": "smoke-mha", "hidden_size": 96, "intermediate_size": 128,
+    "mha": {"name": "smoke-mha", "model": "dense", "hidden_size": 96, "intermediate_size": 128,
             "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 4,
             "vocab_size": 260, "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
             "tie_word_embeddings": False, "qkv_bias": False},
+    "head_dim": {"name": "smoke-head-dim", "model": "dense", "hidden_size": 64,
+                 "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
+                 "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 300,
+                 "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
+                 "tie_word_embeddings": False, "qkv_bias": True},
 }
 
 SMOKE_MIXES = {

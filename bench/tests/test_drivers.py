@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench.drivers import backlog, open_loop
+from bench.models import dense
 from bench.serving import Engine, Source, make_params_for, serve
 from bench.traffic import Backlog, load_mix, spread_order
 from bench.traffic import open_loop as schedule
@@ -49,7 +50,8 @@ def test_steps_sent_ahead_serve_the_same_tokens(ctx_factory):
         ctx = ctx_factory("mha", "backlog", seed=2**31 + 3)
         ctx.mix["dispatch_ahead"] = ahead
         cfg = arch_config(ctx.cfg)
-        engine = Engine(cfg, ctx.mix, make_params_for(build_model(cfg, SERVE_RUN), ctx.seed))
+        engine = Engine(cfg, ctx.mix, make_params_for(build_model(cfg, SERVE_RUN), ctx.seed,
+                                                      dense.leaves(ctx.cfg)))
         engine.warm_up(ctx.mix["prompt_len"]["values"])
         source = Source(backlog=Backlog(ctx.mix, ctx.seed, cfg.vocab_size), depth=6)
         res = serve(engine, source, 1.0, 120.0, ctx.seed)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from bench import check
+from bench.models import dense
 from bench.reference import adapter
 from bench.reference.model import logits_at
 from bench.tests.conftest import LOOSE, SMOKE_CONFIGS
@@ -12,13 +13,13 @@ from bench.traffic import Req
 from bench.weights import arch_config, make_params
 
 
-@pytest.mark.parametrize("config", ["gqa", "mha"])
+@pytest.mark.parametrize("config", ["gqa", "mha", "head_dim"])
 def test_adapter_draws_the_values_the_program_serves(config):
     c = SMOKE_CONFIGS[config]
     from repro.launch.serve import SERVE_RUN
     from repro.models import build_model
 
-    params = make_params(build_model(arch_config(c), SERVE_RUN), seed=2**32 + 9)
+    params = make_params(build_model(arch_config(c), SERVE_RUN), 2**32 + 9, dense.leaves(c))
     for layer in range(c["num_hidden_layers"]):
         w = adapter.layer_weights(c, 2**32 + 9, layer)
         assert np.array_equal(w["wq"], params["layers"]["attn"]["wq"][layer].astype(jnp.float32))
@@ -31,7 +32,7 @@ def test_adapter_draws_the_values_the_program_serves(config):
     assert np.array_equal(top["head"], head.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("config", ["gqa", "mha"])
+@pytest.mark.parametrize("config", ["gqa", "mha", "head_dim"])
 def test_reference_matches_the_program_forward_in_float32(config):
     """The program's own dense forward, run in float32, gives the reference's logits."""
     from dataclasses import replace
@@ -44,7 +45,7 @@ def test_reference_matches_the_program_forward_in_float32(config):
     model = build_model(arch_config(c), run)
     seed = 4
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
-                          make_params(build_model(arch_config(c), SERVE_RUN), seed))
+                          make_params(build_model(arch_config(c), SERVE_RUN), seed, dense.leaves(c)))
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, c["vocab_size"], (2, 24)), jnp.int32)
     with jax.default_matmul_precision("highest"):
         want = jnp.stack([model.prefill(params, tokens[:, : s + 1])[0][:, : c["vocab_size"]]

@@ -2,6 +2,7 @@
 import pytest
 
 from bench import work
+from bench.models import dense
 
 #: hidden 4, 2 query heads of width 2 over 1 KV head, MLP 6, one layer, vocabulary 10
 TINY = {"hidden_size": 4, "num_attention_heads": 2, "num_key_value_heads": 1,
@@ -10,7 +11,7 @@ TINY = {"hidden_size": 4, "num_attention_heads": 2, "num_key_value_heads": 1,
 
 def test_matmul_params_by_hand():
     # q,k,v 4*(2+1+1)*2 = 32; o 2*2*4 = 16; mlp 3*4*6 = 72; head 4*10 = 40
-    assert work.matmul_params(TINY) == 160
+    assert dense.matmul_params(TINY) == 160
 
 
 def test_qwen25_3b_weights_match_the_published_count():
@@ -18,19 +19,19 @@ def test_qwen25_3b_weights_match_the_published_count():
          "intermediate_size": 11008, "num_hidden_layers": 36, "vocab_size": 151936,
          "qkv_bias": True}
     # 36 * (2048*20*128 + 2048*2048 + 3*2048*11008) + 2048*151936
-    assert work.matmul_params(q) == 3_085_697_024
+    assert dense.matmul_params(q) == 3_085_697_024
 
 
 def test_paged_attention_call_by_hand():
-    flops, nbytes = work.paged_attention_call(TINY, [3, 0])
+    flops, nbytes = dense.paged_attention_call(TINY, [3, 0])
     assert flops == 4 * 2 * 2 * 3  # q.k and p.v over 3 tokens, 2 heads of width 2
     # k and v of 3 tokens (2*1*2*3 = 12 values), q and o of both rows (2*2*2*2 = 16)
     assert nbytes == (12 + 16) * 2
 
 
 def test_decode_step_counts_live_rows_only():
-    assert work.decode_step_flops(TINY, [3, 0]) == 2 * 160 * 1 + 48
-    assert work.decode_step_flops(TINY, [0, 0]) == 0
+    assert dense.decode_step_flops(TINY, [3, 0]) == 2 * 160 * 1 + 48
+    assert dense.decode_step_flops(TINY, [0, 0]) == 0
 
 
 def test_roofline_names_its_bound():

@@ -41,21 +41,22 @@ def main() -> int:
     import numpy as np
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    from bench import work
+    from bench import models
     from bench.serving import Engine, Source, make_params_for, serve
     from bench.traffic import load_mix, open_loop, percentile
-    from bench.weights import arch_config, load_config
+    from bench.weights import load_config
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     w = {x["name"]: x for x in bench["workloads"]}[args.workload]
     c, mix = load_config(w["config"]), load_mix(w["traffic"])
-    cfg = arch_config(c)
+    module = models.load(c)
+    cfg = module.arch_config(c)
     from repro.launch.serve import SERVE_RUN
     from repro.models import build_model
 
     t = time.perf_counter()
-    engine = Engine(cfg, mix, make_params_for(build_model(cfg, SERVE_RUN), args.seed),
-                    work=partial(work.decode_step_flops, c))
+    engine = Engine(cfg, mix, make_params_for(build_model(cfg, SERVE_RUN), args.seed, module.leaves(c)),
+                    work=partial(module.decode_step_flops, c))
     engine.warm_up(mix["prompt_len"]["values"])
     print(f"setup {time.perf_counter() - t:.1f} s", file=sys.stderr, flush=True)
     for rate in args.rates:
