@@ -27,10 +27,20 @@ class ArchConfig:
     act: str = "swiglu"  # swiglu | gelu
     rope_theta: float = 1e4
     norm_eps: float = 1e-5
-    # moe
+    # moe: the router chooses among n_experts; a layer may hold a share of
+    # them (experts_held from expert_first; 0 = all), as expert parallelism
+    # divides them between chips
     n_experts: int = 0
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    router: str = "topk"  # topk (softmax, renormalised) | sparsemixer (Phi-3.5-MoE)
+    router_jitter: float = 0.01  # sparsemixer's threshold eps (router_jitter_noise)
+    experts_held: int = 0
+    expert_first: int = 0
+    # norms and biases beyond the q/k/v bias
+    norm: str = "rms"  # rms | layer (LayerNorm with weight and bias)
+    o_bias: bool = False
+    head_bias: bool = False
     # ssm / hybrid
     ssm_state: int = 0
     attn_every: int = 0  # hybrid: shared attention block after every k SSM layers
@@ -47,6 +57,11 @@ class ArchConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def vocab_padded(self) -> int:

@@ -9,7 +9,17 @@
   The beyond-paper optimisation used in §Perf hillclimbing.
 
 Both are capacity-based (static shapes; overflow tokens are dropped and
-their residual passes through — standard practice at scale).
+their residual passes through — standard practice at scale). Training
+uses them, with the configuration's router.
+
+Serving (prefill and decode) uses ``moe_dropless``: every live token's
+pairs reach their experts, sorted by expert into one grouped matmul over
+the experts held here (`repro.kernels.moe_gmm`). The router chooses
+among all the model's experts; a layer that holds a share of them (the
+expert-parallel cut) computes that share's part of the result alone.
+
+Routers: ``topk`` (softmax, top-k, renormalised) and ``sparsemixer``
+(Phi-3.5-MoE's top-2 at inference, `sparsemixer_top2`).
 """
 from __future__ import annotations
 
@@ -17,12 +27,48 @@ import jax
 import jax.numpy as jnp
 
 
-def router_topk(x, w_router, k: int):
+def router_topk(x, w_router, k: int, router: str = "topk", eps: float = 0.01):
     """x: (T, d) -> (gates (T,k) f32, idx (T,k) int32, logits (T,E))."""
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32))
-    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+    gates, idx = route(logits, k, router, eps)
     return gates, idx, logits
+
+
+def route(logits, k: int, router: str = "topk", eps: float = 0.01):
+    """(T, E) router logits -> (gates (T, k) f32, experts (T, k) int32)."""
+    if router == "sparsemixer":
+        if k != 2:
+            raise ValueError(f"sparsemixer routes each token to 2 experts, not {k}")
+        return sparsemixer_top2(logits, eps)
+    if router != "topk":
+        raise ValueError(f"unknown router {router!r}")
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9), idx
+
+
+def _sparsemixer_pick(fill, s, eps):
+    """The best expert of ``fill`` and its weight: the softmax of ``fill`` with
+    every expert i masked out where (top - s_i) / max(|s_i|, top) > 2 eps."""
+    e = jnp.argmax(fill, axis=-1)
+    top = jnp.take_along_axis(fill, e[:, None], axis=-1)
+    masked = jnp.where((top - s) / jnp.maximum(jnp.abs(s), top) > 2 * eps, -jnp.inf, fill)
+    w = jnp.take_along_axis(jax.nn.softmax(masked, axis=-1), e[:, None], axis=-1)[:, 0]
+    return w, e.astype(jnp.int32)
+
+
+def sparsemixer_top2(logits, eps: float):
+    """Phi-3.5-MoE's router at inference (``router_jitter_noise`` = ``eps``).
+
+    The first expert is the best logit, weighted by the softmax over the
+    experts within 2 eps of it (relatively); the second is the best of the
+    rest, weighted likewise over the rest, the threshold measured against
+    the original logits. The two weights are not renormalised.
+    """
+    s = logits.astype(jnp.float32)
+    w1, e1 = _sparsemixer_pick(s, s, eps)
+    rest = jnp.where(jnp.arange(s.shape[-1]) == e1[:, None], -jnp.inf, s)
+    w2, e2 = _sparsemixer_pick(rest, s, eps)
+    return jnp.stack([w1, w2], axis=-1), jnp.stack([e1, e2], axis=-1)
 
 
 def load_balancing_loss(logits: jax.Array, idx: jax.Array, n_experts: int) -> jax.Array:
@@ -42,7 +88,7 @@ def _expert_ffn(xe, wi, wg, wo):
 
 
 def moe_einsum(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
-               group_size: int = 512):
+               group_size: int = 512, router: str = "topk", eps: float = 0.01):
     """GShard-style dispatch. x: (B, S, d) -> (B, S, d), aux_loss.
 
     Memory-sane einsum form: the (g, gs, E, C) dispatch/combine one-hots
@@ -54,7 +100,7 @@ def moe_einsum(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
     b, s, d = x.shape
     t = b * s
     xf = x.reshape(t, d)
-    gates, idx, logits = router_topk(xf, params["router"], k)
+    gates, idx, logits = router_topk(xf, params["router"], k, router, eps)
     aux = load_balancing_loss(logits, idx, n_experts)
 
     g = max(1, t // group_size)
@@ -95,7 +141,7 @@ def moe_einsum(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
 
 
 def moe_sort(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
-             group_size: int = 4096):
+             group_size: int = 4096, router: str = "topk", eps: float = 0.01):
     """Sort-based dispatch: no dense one-hot matmuls.
 
     Within each group: flatten (token, slot) pairs, sort by expert id,
@@ -105,7 +151,7 @@ def moe_sort(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
     b, s, d = x.shape
     t = b * s
     xf = x.reshape(t, d)
-    gates, idx, logits = router_topk(xf, params["router"], k)
+    gates, idx, logits = router_topk(xf, params["router"], k, router, eps)
     aux = load_balancing_loss(logits, idx, n_experts)
 
     g = max(1, t // group_size)
@@ -142,11 +188,42 @@ def moe_sort(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
 
 
 def moe_layer(x, params, n_experts: int, k: int, capacity_factor: float = 1.25,
-              impl: str = "einsum", group_size: int | None = None):
+              impl: str = "einsum", group_size: int | None = None, router: str = "topk",
+              eps: float = 0.01):
     if impl == "einsum":
         return moe_einsum(x, params, n_experts, k, capacity_factor,
-                          group_size=group_size or 1024)
+                          group_size=group_size or 1024, router=router, eps=eps)
     if impl == "sort":
         return moe_sort(x, params, n_experts, k, capacity_factor,
-                        group_size=group_size or 4096)
+                        group_size=group_size or 4096, router=router, eps=eps)
     raise ValueError(f"unknown moe impl {impl!r}")
+
+
+def moe_dropless(x, params, n_experts: int, k: int, first: int = 0,
+                 router: str = "topk", eps: float = 0.01, live=None):
+    """Every live token's part of the layer from the experts held here.
+
+    x: (T, d); ``params``: ``router`` (d, n_experts) over every expert, and
+    ``wi``, ``wg``, ``wo`` of the held experts ``first`` to ``first + held``.
+    The router scores every expert in float32; each token's k (token,
+    expert) pairs are sorted by expert, the held experts' rows go through
+    one grouped matmul each for ``wi``, ``wg`` and ``wo``, and ``gate *
+    expert(x)`` is added back to the token. Pairs of experts held elsewhere
+    add nothing here. Rows where ``live`` (T,) is False make no pairs.
+    Returns (T, d) in x's dtype.
+    """
+    from repro.kernels.moe_gmm import held_expert_ffn
+
+    t, d = x.shape
+    logits = jnp.dot(x.astype(jnp.float32), params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, idx = route(logits, k, router, eps)
+    if live is not None:  # n_experts: past every group, so in none
+        idx = jnp.where(live[:, None], idx, n_experts)
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    tok = order // k
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1, mode="drop")
+    y = held_expert_ffn(x[tok], params["wi"], params["wg"], params["wo"], sizes, first)
+    y = y * gates.reshape(-1)[order][:, None]
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(y).astype(x.dtype)

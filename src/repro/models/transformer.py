@@ -27,13 +27,27 @@ from repro.configs import ArchConfig, RunConfig
 
 from . import rwkv as rwkv_mod
 from . import ssm as ssm_mod
-from .layers import apply_rope, attention, full_attention, mlp_swiglu, rmsnorm
-from .moe import moe_layer
+from .layers import apply_rope, attention, full_attention, layernorm, mlp_swiglu, rmsnorm
+from .moe import moe_dropless, moe_layer
 from .params import cast_tree, dense_init, embed_init, stack_layers
 
 
 def _dt(run: RunConfig):
     return jnp.dtype(run.compute_dtype)
+
+
+def _norm(x, p, name: str, cfg: ArchConfig):
+    """RMSNorm by ``p[name]``, or LayerNorm by it and ``p[name + "_b"]``."""
+    if cfg.norm == "layer":
+        return layernorm(x, p[name], p[name + "_b"], cfg.norm_eps)
+    return rmsnorm(x, p[name], cfg.norm_eps)
+
+
+def _norm_init(cfg: ArchConfig, *names: str) -> dict:
+    p = {n: jnp.ones((cfg.d_model,), jnp.float32) for n in names}
+    if cfg.norm == "layer":
+        p |= {n + "_b": jnp.zeros((cfg.d_model,), jnp.float32) for n in names}
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -52,7 +66,15 @@ def init_attn(key, cfg: ArchConfig):
         p["bq"] = jnp.zeros((hq * hd,), jnp.float32)
         p["bk"] = jnp.zeros((hkv * hd,), jnp.float32)
         p["bv"] = jnp.zeros((hkv * hd,), jnp.float32)
+    if cfg.o_bias:
+        p["bo"] = jnp.zeros((d,), jnp.float32)
     return p
+
+
+def _out_proj(o, p):
+    """Attention output (..., Hq * Dh) -> (..., d), plus the o bias where there is one."""
+    y = jnp.einsum("...e,ed->...d", o, p["wo"].astype(o.dtype))
+    return y + p["bo"].astype(o.dtype) if "bo" in p else y
 
 
 def _qkv(x, p, cfg: ArchConfig, positions, rope: bool = True):
@@ -92,8 +114,7 @@ def attn_block(x, p, cfg: ArchConfig, run: RunConfig, positions, causal=True, ro
             q_chunk=run.q_chunk, kv_chunk=run.kv_chunk,
             unroll=run.scan_unroll, skip_masked_blocks=run.skip_masked_blocks,
         )
-    o = o.reshape(b, s, -1)
-    return jnp.einsum("bse,ed->bsd", o, p["wo"].astype(x.dtype)), (k, v)
+    return _out_proj(o.reshape(b, s, -1).astype(x.dtype), p), (k, v)
 
 
 def attn_block_decode_paged(
@@ -121,8 +142,7 @@ def attn_block_decode_paged(
     v_pages = v_pages.at[:, page, off].set(v[:, 0].swapaxes(0, 1).astype(cdt))
     new_len = jnp.where(live, kv_len + 1, 0)
     o = paged_decode_attention(q[:, 0], k_pages, v_pages, page_table, new_len)
-    o = o.reshape(b, -1).astype(x.dtype)
-    return jnp.einsum("be,ed->bd", o, p["wo"].astype(x.dtype)), k_pages, v_pages
+    return _out_proj(o.reshape(b, -1).astype(x.dtype), p), k_pages, v_pages
 
 
 def attn_block_decode(x, p, cfg: ArchConfig, run: RunConfig, k_cache, v_cache, pos):
@@ -140,8 +160,7 @@ def attn_block_decode(x, p, cfg: ArchConfig, run: RunConfig, k_cache, v_cache, p
         q, k_cache.astype(q.dtype), v_cache.astype(q.dtype),
         causal=False, kv_len=jnp.full((b,), pos + 1),
     )
-    o = o.reshape(b, -1)
-    return jnp.einsum("be,ed->bd", o, p["wo"].astype(x.dtype)), k_cache, v_cache
+    return _out_proj(o.reshape(b, -1).astype(x.dtype), p), k_cache, v_cache
 
 
 def init_mlp(key, cfg: ArchConfig):
@@ -155,10 +174,11 @@ def init_mlp(key, cfg: ArchConfig):
 
 
 def init_moe(key, cfg: ArchConfig):
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over every expert; the weights of the experts held here."""
+    d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_held
     ks = jax.random.split(key, 4)
     return {
-        "router": dense_init(ks[0], d, e),
+        "router": dense_init(ks[0], d, cfg.n_experts),
         "wi": jax.vmap(lambda k: dense_init(k, d, ff))(jax.random.split(ks[1], e)),
         "wg": jax.vmap(lambda k: dense_init(k, d, ff))(jax.random.split(ks[2], e)),
         "wo": jax.vmap(lambda k: dense_init(k, ff, d))(jax.random.split(ks[3], e)),
@@ -171,11 +191,7 @@ def init_moe(key, cfg: ArchConfig):
 def init_layer(key, cfg: ArchConfig):
     if cfg.family in ("dense", "moe"):
         k1, k2 = jax.random.split(key)
-        p = {
-            "ln1": jnp.ones((cfg.d_model,), jnp.float32),
-            "attn": init_attn(k1, cfg),
-            "ln2": jnp.ones((cfg.d_model,), jnp.float32),
-        }
+        p = _norm_init(cfg, "ln1", "ln2") | {"attn": init_attn(k1, cfg)}
         p["moe" if cfg.family == "moe" else "mlp"] = (
             init_moe(k2, cfg) if cfg.family == "moe" else init_mlp(k2, cfg)
         )
@@ -190,20 +206,36 @@ def init_layer(key, cfg: ArchConfig):
     raise ValueError(cfg.family)
 
 
-def apply_layer(x, p, cfg: ArchConfig, run: RunConfig, positions):
-    """Train/prefill layer body. Returns (x, (aux_loss, cache))."""
+def _moe_serve(h, p, cfg: ArchConfig, live=None):
+    """Serving's dropless expert layer over (..., d) tokens; ``live`` (T,) or None."""
+    y = moe_dropless(h.reshape(-1, h.shape[-1]), p, cfg.n_experts, cfg.experts_per_token,
+                     cfg.expert_first, cfg.router, cfg.router_jitter, live)
+    return y.reshape(h.shape)
+
+
+def apply_layer(x, p, cfg: ArchConfig, run: RunConfig, positions, dropless: bool = False):
+    """Train/prefill layer body. Returns (x, (aux_loss, cache)).
+
+    ``dropless``: a moe layer serves every token (prefill) rather than
+    dispatching by capacity (training).
+    """
     from . import sharding_ctx as sc
 
     if cfg.family in ("dense", "moe"):
-        a, (k, v) = attn_block(rmsnorm(x, p["ln1"], cfg.norm_eps), p["attn"], cfg, run, positions)
+        a, (k, v) = attn_block(_norm(x, p, "ln1", cfg), p["attn"], cfg, run, positions)
         x = x + a
         if run.constrain_activations:
             x = sc.constrain(x, sc.dp_axes(), None, None)
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
+        h = _norm(x, p, "ln2", cfg)
+        if cfg.family == "moe" and dropless:
+            m, aux = _moe_serve(h, p["moe"], cfg), 0.0
+        elif cfg.family == "moe":
+            if cfg.n_held != cfg.n_experts:
+                raise ValueError("capacity dispatch needs every expert held")
             m, aux = moe_layer(
                 h, p["moe"], cfg.n_experts, cfg.experts_per_token,
                 cfg.capacity_factor, impl=run.moe_impl, group_size=run.moe_group,
+                router=cfg.router, eps=cfg.router_jitter,
             )
         else:
             m = mlp_swiglu(h, p["mlp"]["wi"], p["mlp"]["wg"], p["mlp"]["wo2"],
@@ -227,18 +259,13 @@ def apply_layer(x, p, cfg: ArchConfig, run: RunConfig, positions):
     raise ValueError(cfg.family)
 
 
-def _decode_tail(x, a, p, cfg: ArchConfig, run: RunConfig):
-    """Dense/moe decode-layer tail: attn residual + norm + mlp/moe residual."""
+def _decode_tail(x, a, p, cfg: ArchConfig, run: RunConfig, live=None):
+    """Dense/moe decode-layer tail: attn residual + norm + mlp/moe residual.
+    ``live`` (B,): rows that route to experts (all where None)."""
     x = x + a
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    h = _norm(x, p, "ln2", cfg)
     if cfg.family == "moe":
-        # decode must never drop: capacity covers every (token, slot)
-        m, _ = moe_layer(
-            h[:, None], p["moe"], cfg.n_experts, cfg.experts_per_token,
-            capacity_factor=float(cfg.n_experts), impl=run.moe_impl,
-            group_size=min(x.shape[0], run.moe_group or x.shape[0]),
-        )
-        m = m[:, 0]
+        m = _moe_serve(h, p["moe"], cfg, live)
     else:
         m = mlp_swiglu(h[:, None], p["mlp"]["wi"], p["mlp"]["wg"], p["mlp"]["wo2"])[:, 0]
     return x + m
@@ -248,7 +275,7 @@ def apply_layer_decode(x, p, cache, cfg: ArchConfig, run: RunConfig, pos):
     """Single-token layer body. Returns (x, new_cache)."""
     if cfg.family in ("dense", "moe"):
         a, k, v = attn_block_decode(
-            rmsnorm(x, p["ln1"], cfg.norm_eps), p["attn"], cfg, run,
+            _norm(x, p, "ln1", cfg), p["attn"], cfg, run,
             cache["k"], cache["v"], pos,
         )
         return _decode_tail(x, a, p, cfg, run), {"k": k, "v": v}
@@ -267,10 +294,10 @@ def apply_layer_decode_paged(
 ):
     """Paged single-token layer body (dense/moe only). Returns (x, new_cache)."""
     a, k_pages, v_pages = attn_block_decode_paged(
-        rmsnorm(x, p["ln1"], cfg.norm_eps), p["attn"], cfg, run,
+        _norm(x, p, "ln1", cfg), p["attn"], cfg, run,
         cache["k"], cache["v"], page_table, kv_len, live,
     )
-    return _decode_tail(x, a, p, cfg, run), {"k": k_pages, "v": v_pages}
+    return _decode_tail(x, a, p, cfg, run, live), {"k": k_pages, "v": v_pages}
 
 
 # ---------------------------------------------------------------------------
@@ -290,12 +317,12 @@ class DecoderLM:
         """
         cfg = self.cfg
         ks = jax.random.split(key, 6)
-        params = {
-            "embed": embed_init(ks[0], cfg.vocab_padded, cfg.d_model),
-            "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
-        }
+        params = {"embed": embed_init(ks[0], cfg.vocab_padded, cfg.d_model)}
+        params |= _norm_init(cfg, "final_norm")
         if not cfg.tie_embeddings:
             params["head"] = dense_init(ks[1], cfg.d_model, cfg.vocab_padded)
+        if cfg.head_bias:
+            params["head_b"] = jnp.zeros((cfg.vocab_padded,), jnp.float32)
         if cfg.family == "hybrid":
             g, gsz, tail = self._hybrid_layout()
             params["groups"] = stack_layers(
@@ -324,17 +351,16 @@ class DecoderLM:
     def _logits(self, params, x):
         head = params.get("head")
         w = (head if head is not None else params["embed"].T).astype(x.dtype)
-        if head is None:
-            return jnp.einsum("...d,dv->...v", x, w)
-        return jnp.einsum("...d,dv->...v", x, w)
+        y = jnp.einsum("...d,dv->...v", x, w)
+        return y + params["head_b"].astype(x.dtype) if "head_b" in params else y
 
-    def _layer_scan(self, params, x, positions):
+    def _layer_scan(self, params, x, positions, dropless: bool = False):
         """Run all layers; returns (x, aux_sum, cache_pytree)."""
         cfg, run = self.cfg, self.run
 
         def body(carry, p_l):
             h, aux = carry
-            h2, (a, cache) = apply_layer(h, p_l, cfg, run, positions)
+            h2, (a, cache) = apply_layer(h, p_l, cfg, run, positions, dropless)
             return (h2, aux + a), cache
 
         body_fn = jax.checkpoint(body) if run.remat == "layer" else body
@@ -408,7 +434,7 @@ class DecoderLM:
             x = self._embed(params, inputs, dtype)
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         x, aux, _ = self._layer_scan(params, x, positions)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params, "final_norm", cfg)
         loss = self._ce(params, x, targets)
         if cfg.family == "moe":
             loss = loss + 0.01 * aux / cfg.n_layers
@@ -514,8 +540,8 @@ class DecoderLM:
         max_len = max_len or s
         x = self._embed(params, tokens, dtype)
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        x, _, caches = self._layer_scan(params, x, positions)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x, _, caches = self._layer_scan(params, x, positions, dropless=True)
+        x = _norm(x, params, "final_norm", cfg)
         logits = self._logits(params, x[:, -1]).astype(jnp.float32)
         cache = self._package_cache(caches, b, s, max_len)
         return logits, cache
@@ -604,7 +630,7 @@ class DecoderLM:
             if tail:
                 x, tail_caches = stack_step(x, params["tail"], cache["tail"], tail)
                 new_cache["tail"] = tail_caches
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params, "final_norm", cfg)
         return self._logits(params, x).astype(jnp.float32), new_cache
 
     def decode_step_paged(self, params, cache, token, page_table, kv_len, live):
@@ -639,5 +665,5 @@ class DecoderLM:
                 x, c_new = body(x, (p_l, c_l))
                 news.append(c_new)
             caches = jax.tree.map(lambda *xs: jnp.stack(xs), *news)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params, "final_norm", cfg)
         return self._logits(params, x).astype(jnp.float32), {"layers": caches}

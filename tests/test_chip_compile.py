@@ -123,3 +123,44 @@ def test_full_width_paged_decode_step_fits_and_holds_kernel(one_chip, qwen, monk
         params, cache, i32((B,)), i32((B, TABLE)), i32((B,)), live).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("rows", [64, 1024], ids=["decode-32-slots", "prefill-512"])
+def test_held_expert_gmm_compiles_at_phimoe_widths(one_chip, rows, monkeypatch):
+    """Phi-3.5-MoE's held experts (8 of 16, d 4096, ff 6400): the SwiGLU's three
+    grouped matmuls at a decode step's 32 x 2 routed rows and a 512-token prefill's."""
+    import repro.kernels.moe_gmm.ops as gmm_ops
+
+    monkeypatch.setattr(gmm_ops, "interpret_default", lambda: False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    up = sds((8, 4096, 6400), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda x, wi, wg, wo, sizes: gmm_ops.held_expert_ffn(x, wi, wg, wo, sizes, 0)
+    ).lower(sds((rows, 4096), jnp.bfloat16), up, up, sds((8, 6400, 4096), jnp.bfloat16),
+            sds((16,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_phimoe_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
+    """phi3.5-moe as ``bench/configs/phi3.5-moe.json`` cuts it (8 layers, experts
+    0-7 of 16) with the batch-decode mix's pool, 32 slots of 97 pages: the decode
+    step holds both kernels and fits one chip beside its 11.27 GB of weights."""
+    from dataclasses import replace
+
+    import repro.kernels.moe_gmm.ops as gmm_ops
+    import repro.kernels.paged_attention.ops as paged_ops
+
+    monkeypatch.setattr(paged_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(gmm_ops, "interpret_default", lambda: False)
+    cfg = replace(get_config("phi3.5-moe-42b-a6.6b"), n_layers=8, experts_held=8)
+    model = build_model(cfg, SERVE_RUN)
+    params = _on(jax.eval_shape(model.init, jax.random.PRNGKey(0)), one_chip)
+    rows, table = 32, 97
+    cache = _on(jax.eval_shape(lambda: model.init_paged_cache(1 + rows * table, PAGE)), one_chip)
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(model.decode_step_paged, donate_argnums=(1,)).lower(
+        params, cache, i32((rows,)), i32((rows, table)), i32((rows,)), live).compile()
+    text = compiled.as_text()
+    assert "held_expert_gmm" in text and "paged_decode_attention" in text
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
